@@ -54,10 +54,12 @@ def main() -> int:
         assert acc["blocks_visited"] < dense["blocks_total"], acc
         out_p, lse_p = flash_decode(q, k, v, total_len, rank, kvp=kvp,
                                     rr_block=rr, window=window,
-                                    block_s=block_s, prune=True)
+                                    block_s=block_s, prune=True,
+                                    interpret=True)
         out_d, lse_d = flash_decode(q, k, v, total_len, rank, kvp=kvp,
                                     rr_block=rr, window=window,
-                                    block_s=block_s, prune=False)
+                                    block_s=block_s, prune=False,
+                                    interpret=True)
         np.testing.assert_array_equal(np.asarray(out_p), np.asarray(out_d))
         np.testing.assert_array_equal(np.asarray(lse_p), np.asarray(lse_d))
         print(f"[prune_smoke] decode window={window}: "
@@ -86,10 +88,10 @@ def main() -> int:
     valid = int(local_valid_len(jnp.asarray(total_len), rank, kvp, rr))
     assert accp["blocks_visited"] / (b * kh) <= cdiv(valid, ps) + 1
     out_f, _ = flash_decode(q, k, v, total_len, rank, kvp=kvp, rr_block=rr,
-                            block_s=ps, prune=True)
+                            block_s=ps, prune=True, interpret=True)
     out_g, _ = flash_decode(q, pool_k, pool_v, total_len, rank, kvp=kvp,
                             rr_block=rr, prune=True,
-                            block_tables=jnp.asarray(tables))
+                            block_tables=jnp.asarray(tables), interpret=True)
     np.testing.assert_array_equal(np.asarray(out_f), np.asarray(out_g))
     print(f"[prune_smoke] paged decode: {accp['blocks_visited']} blocks "
           f"through the block table (== fixed), outputs bit-exact")
@@ -108,9 +110,9 @@ def main() -> int:
     assert abs(frac - (n + 1) / (2 * n)) < 1e-9, (frac, n)
     assert frac <= 0.56, frac
     out_p = flash_prefill(qp, kp, vp, causal=True, blk_q=blk, blk_k=blk,
-                          prune=True)
+                          prune=True, interpret=True)
     out_d = flash_prefill(qp, kp, vp, causal=True, blk_q=blk, blk_k=blk,
-                          prune=False)
+                          prune=False, interpret=True)
     np.testing.assert_array_equal(np.asarray(out_p), np.asarray(out_d))
     print(f"[prune_smoke] prefill causal: {frac * 100:.0f}% of the "
           f"rectangle visited, outputs bit-exact")

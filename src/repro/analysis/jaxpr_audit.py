@@ -32,10 +32,11 @@ import numpy as np
 
 from repro.analysis.findings import Finding, Report
 
-# psum traced inside shard_map lowers to the ``psum2`` primitive (with an
-# ``axes`` param instead of ``axis_name``) — normalized back to "psum" in
-# collect_collectives so expected-count specs stay primitive-name based
-_COMBINE_PRIMS = ("all_to_all", "all_gather", "psum", "psum2")
+# psum traced inside a replication-checked shard_map is the
+# ``psum_invariant`` primitive (with an ``axes`` param instead of
+# ``axis_name``) — normalized back to "psum" in collect_collectives so
+# expected-count specs stay primitive-name based
+_COMBINE_PRIMS = ("all_to_all", "all_gather", "psum", "psum_invariant")
 
 
 def _axis_tuple(val) -> tuple:
@@ -61,7 +62,7 @@ def collect_collectives(jaxpr, path="") -> list[dict]:
         if name in _COMBINE_PRIMS or name == "axis_index":
             axes = _axis_tuple(eqn.params.get("axis_name",
                                               eqn.params.get("axes")))
-            prim = "psum" if name == "psum2" else name
+            prim = "psum" if name == "psum_invariant" else name
             out.append({"prim": prim, "axes": axes, "path": here})
         for v in eqn.params.values():
             sub = getattr(v, "jaxpr", None)

@@ -299,9 +299,14 @@ def helix_attention(mesh: Mesh, hx: HelixConfig, q, kcache, vcache, total_len,
         if d_pad != d_flat:
             flat = jnp.pad(flat, ((0, 0), (0, d_pad - d_flat)))
         frags = flat.reshape(bl, kvp, sl).transpose(1, 0, 2)  # [KVP, B, sl]
-        frags = jax.lax.all_to_all(frags, kvp_axes, split_axis=0,
-                                   concat_axis=0, tiled=False)
-        lses = jax.lax.all_gather(lse, kvp_axes, axis=0, tiled=False)
+        if kvp_axes:
+            frags = jax.lax.all_to_all(frags, kvp_axes, split_axis=0,
+                                       concat_axis=0, tiled=False)
+            lses = jax.lax.all_gather(lse, kvp_axes, axis=0, tiled=False)
+        else:
+            # KVP=1: nothing to exchange (a collective over no axes drops
+            # the rank axis); the local fragment is the whole combine input
+            lses = lse[None]
         my_slice = jax.lax.dynamic_index_in_dim(
             head_idx_table, rank, axis=0, keepdims=False)
         combined = combine_fragments(frags, lses, my_slice)   # [B, sl]

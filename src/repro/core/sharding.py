@@ -42,6 +42,34 @@ class MeshPolicy:
         return jax.lax.with_sharding_constraint(
             x, NamedSharding(self.mesh, self.spec(*axes)))
 
+    def per_shard(self, fn, args, axes, out_axes):
+        """``fn(*args)`` run on each device's shard under ``shard_map``.
+
+        GSPMD cannot partition a Pallas kernel, so its caller splits the
+        operands explicitly.  ``axes[i]`` names argument i's logical role
+        per dim (as for ``__call__``), ``out_axes`` the result's.  A role
+        whose mesh axes do not divide every dim it labels (a packed prefill
+        of 3 requests over 4 data shards) is replicated instead."""
+        import math
+
+        from repro.utils import shard_map
+        dims: dict[str, list[int]] = {}
+        for x, ax in zip(args, axes):
+            for d, role in zip(jax.numpy.shape(x), ax):
+                if role:
+                    dims.setdefault(role, []).append(d)
+        keep = {r for r, ds in dims.items()
+                if all(d % math.prod(self.mesh.shape[a]
+                                     for a in self.roles.get(r, ())) == 0
+                       for d in ds)}
+
+        def spec(ax):
+            return self.spec(*[a if a in keep else None for a in ax])
+
+        return shard_map(fn, mesh=self.mesh,
+                         in_specs=tuple(spec(ax) for ax in axes),
+                         out_specs=spec(out_axes), check_vma=False)(*args)
+
 
 def train_roles(mesh: Mesh) -> dict[str, tuple[str, ...]]:
     names = mesh.axis_names
@@ -53,10 +81,6 @@ def train_roles(mesh: Mesh) -> dict[str, tuple[str, ...]]:
 
 
 # ------------------------------------------------------------------ helix
-# back-compat alias: the canonical list lives in the kernel registry
-from repro.kernels.registry import BACKENDS as ATTN_BACKENDS  # noqa: E402
-
-
 @dataclasses.dataclass(frozen=True)
 class HelixConfig:
     """How the mesh axes are consumed by the Helix decode phases.
@@ -67,7 +91,9 @@ class HelixConfig:
 
     Kernel backends: the four ``*_backend`` fields select, per kernel family,
     one of ``"ref"`` | ``"pallas-interpret"`` | ``"pallas"`` from the unified
-    registry (kernels/registry.py) — ``attn_backend`` routes flash_decode
+    registry (kernels/registry.py); left unset (None) they take
+    ``registry.default_backend`` — compiled ``pallas`` on a TPU, ``ref``
+    elsewhere.  ``attn_backend`` routes flash_decode
     (the Helix decode attention inside the shard_map), ``prefill_backend``
     routes flash_prefill (full-sequence attention in prefill/train),
     ``ssd_backend`` routes ssd_prefill (the Mamba2 SSD scan core) and
@@ -94,11 +120,12 @@ class HelixConfig:
     #   per-rank page rows (rr_block) take over as the block size; setting
     #   attn_block_s == rr_block makes fixed and paged online-softmax block
     #   partitions identical, hence bit-exact parity between the layouts.
-    # --- per-family kernel backends (kernels/registry.py) ---
-    attn_backend: str = "ref"            # flash_decode (helix decode attn)
-    prefill_backend: str = "ref"         # flash_prefill (prefill/train attn)
-    ssd_backend: str = "ref"             # ssd_prefill (mamba2 SSD core)
-    matmul_backend: str = "ref"          # w8a16_matmul (int8-weight matmul)
+    # --- per-family kernel backends (kernels/registry.py); None = the
+    # platform default (registry.default_backend) ---
+    attn_backend: str | None = None      # flash_decode (helix decode attn)
+    prefill_backend: str | None = None   # flash_prefill (prefill/train attn)
+    ssd_backend: str | None = None       # ssd_prefill (mamba2 SSD core)
+    matmul_backend: str | None = None    # w8a16_matmul (int8-weight matmul)
     fuse_append: bool = True             # fuse the rr-slot KV append into the
     #   flash-decode kernel epilogue (saves one cache HBM round-trip per
     #   layer per step).  Only active on the pallas backends, for round-robin
@@ -130,6 +157,9 @@ class HelixConfig:
     def __post_init__(self):
         from repro.kernels import registry
         for field, family in registry.FAMILY_FIELDS.items():
+            if getattr(self, field) is None:
+                object.__setattr__(self, field,
+                                   registry.default_backend(family))
             assert getattr(self, field) in registry.BACKENDS, \
                 (field, getattr(self, field), registry.BACKENDS)
 

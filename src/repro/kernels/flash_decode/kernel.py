@@ -67,8 +67,9 @@ the *pre-append* cache plus the new token's K/V row, and the kernel
 
   1. substitutes the new row into the streamed K/V tile in VMEM for the
      attention compute (the HBM block containing the target slot is stale),
-  2. writes the row back to the cache through a (1, 1, 1, hsz) output window
-     whose index_map derives the target slot from the prefetched per-request
+  2. writes the row back to the cache through a (1, 1, rw, hsz) output
+     window (``append_rows``: the sublane tile holding the row) whose
+     index_map derives the target slot from the prefetched per-request
      lengths — ``input_output_aliases`` makes these outputs *the same HBM
      buffers* as the K/V inputs, so the rest of the cache is untouched and
      the separate append pass (one full-cache HBM round-trip per layer per
@@ -76,9 +77,10 @@ the *pre-append* cache plus the new token's K/V row, and the kernel
 
 The row window is re-written (idempotently) at every S-block step, so the
 kernel is correct under both write-back policies Pallas implementations use
-(every visit, or last visit only).  Non-owner ranks (round-robin: the new
-position lives on exactly one KVP rank) write back the unmodified row read
-through a matching (1, 1, 1, hsz) *input* window.  Append mode composes with
+(every visit, or last visit only).  The window's other rows, and the whole
+window on non-owner ranks (round-robin: the new position lives on exactly
+one KVP rank), are written back unmodified from a matching (1, 1, rw, hsz)
+*input* window.  Append mode composes with
 per-request [B] lengths (each row appends at its own slot) but excludes the
 contiguous layout (static cross-attention KV is never appended) and the
 ``slot_offset`` cache-slice path — the Helix caller falls back to the
@@ -87,8 +89,8 @@ unfused ``append_kv`` there (core/helix.py).
 int8 append (append + quant): the new token's row arrives *unquantized*
 (f32); the kernel quantizes it in VMEM with the same per-(B, Kh) symmetric
 formula as ``core/helix.quantize_kv_token`` (scale = max|x|/127, round,
-clip) and persists payload + scale through aliased (1, 1, 1, hsz) / (1, 1, 1)
-row windows, so the fused path is bit-exact with ``append_kv_quant`` followed
+clip) and persists payload + scale through aliased (1, 1, rw, hsz) /
+(1, 1, rw) windows, so the fused path is bit-exact with ``append_kv_quant`` followed
 by the attention pass.
 """
 from __future__ import annotations
@@ -104,6 +106,16 @@ from repro.utils import NEG_INF
 from repro.kernels.flash_decode.ref import local_valid_len
 from repro.kernels.pruning import phys_block as _phys_block
 from repro.kernels.pruning import table_block as _table_block
+
+
+def append_rows(block_s: int) -> int:
+    """Rows of the fused-append write window: the widest sublane tile
+    (8 rows at 32 bits, 16 at 16, 32 at 8) that divides the S block.  A
+    one-row window is refused by Mosaic (a block's second-minor dim must be
+    a multiple of 8 or the whole array dim); a paged page of rr_block rows
+    is written whole."""
+    import math
+    return math.gcd(block_s, 32)
 
 
 def _append_slot(total_len, kvp: int, rr_block: int, s_max: int):
@@ -188,11 +200,13 @@ def decode_index_maps(*, kvp: int, rr_block: int, block_s: int, s_true: int,
       kv     streamed K/V blocks (1, 1, block_s, hsz); prune-clamped, and
              table-indirected in paged mode
       scale  streamed dequant-scale blocks (1, 1, block_s); same clamp
-      row    fused-append (1, 1, 1, hsz) row window of the new token
-      srow   fused-append (1, 1, 1) scale-row window
+      row    fused-append (1, 1, rw, hsz) window holding the new token's
+             row (``rw = append_rows(block_s)``; block index in rw units)
+      srow   fused-append (1, 1, rw) scale-row window
       q      resident query block (constant along the S axis)
-      new    the new token's (1, 1, hsz) K/V row (resident)
-      lse    the [B, Kh, Qp] log-sum-exp output
+      new    the new token's (1, 1, 1, hsz) K/V row (resident)
+      lse    the [B, Kh, Qp, 1] log-sum-exp output (a column, so the block's
+             minor dims equal the array's — Mosaic's tiling rule)
 
     ``grouped`` (suffix pass of the shared-prefix grouped decode — paged
     only): a fourth prefetch operand ``start [B]`` gives each request's
@@ -202,6 +216,7 @@ def decode_index_maps(*, kvp: int, rr_block: int, block_s: int, s_true: int,
     per request.  Maps then take ``(b, h, s, meta, tl, tables, start)``.
     """
     s_pad = n_blocks * block_s
+    rw = append_rows(block_s)
     assert not grouped or paged, "grouped suffix maps require paged mode"
 
     def logical_block(s, meta_ref, tl_ref, b, *rest):
@@ -234,12 +249,13 @@ def decode_index_maps(*, kvp: int, rr_block: int, block_s: int, s_true: int,
         return kv_idx(b, h, s, meta_ref, tl_ref, *rest)[:3]
 
     def row_idx(b, h, s, meta_ref, tl_ref, *rest):
-        # target row window of the appended token; depends on the prefetched
+        # window holding the appended token's row; depends on the prefetched
         # per-request length only (rank-independent slot formula)
         j_new = _append_slot(tl_ref[b], kvp, rr_block, s_pad)
         if paged:
-            return (rest[0][b, j_new // block_s], h, j_new % block_s, 0)
-        return (b, h, j_new, 0)
+            return (rest[0][b, j_new // block_s], h,
+                    (j_new % block_s) // rw, 0)
+        return (b, h, j_new // rw, 0)
 
     def srow_idx(b, h, s, meta_ref, tl_ref, *rest):
         return row_idx(b, h, s, meta_ref, tl_ref, *rest)[:3]
@@ -248,10 +264,10 @@ def decode_index_maps(*, kvp: int, rr_block: int, block_s: int, s_true: int,
         return (b, h, 0, 0)
 
     def new_idx(b, h, s, *_):
-        return (b, h, 0)
+        return (b, h, 0, 0)
 
     def lse_idx(b, h, s, *_):
-        return (b, h, 0)
+        return (b, h, 0, 0)
 
     return {"kv": kv_idx, "scale": scale_idx, "row": row_idx,
             "srow": srow_idx, "q": q_idx, "new": new_idx, "lse": lse_idx}
@@ -298,8 +314,8 @@ def _decode_kernel(meta_ref, tl_ref, *refs, scale: float,
             # the ungrouped kernel would have used, so continuing the
             # online softmax from here is bit-exact.
             acc_ref[...] = acc0_ref[0, 0]
-            m_ref[...] = m0_ref[0, 0][:, None]
-            l_ref[...] = l0_ref[0, 0][:, None]
+            m_ref[...] = m0_ref[0, 0]
+            l_ref[...] = l0_ref[0, 0]
         else:
             acc_ref[...] = jnp.zeros_like(acc_ref)
             m_ref[...] = jnp.full_like(m_ref, NEG_INF)
@@ -324,24 +340,30 @@ def _decode_kernel(meta_ref, tl_ref, *refs, scale: float,
 
     if append:
         # epilogue: derive the new token's slot/ownership, quantize in quant
-        # mode, and persist the row through the aliased (1,1,1,hsz) output
+        # mode, and persist the row through the aliased (1,1,rw,hsz) output
         # windows (idempotent re-write each S step — correct under both
-        # write-back policies; non-owners restore the row they read).
+        # write-back policies; the window's other rows, and the whole
+        # window on non-owner ranks, are restored from the input window).
         j_new = _append_slot(total_len, kvp, rr_block, n_blocks * block_s)
         owner = (((total_len - 1) // rr_block) % kvp) == rank
-        kn = knew_ref[0, 0]                              # [hsz]
+        rw = krow_in_ref.shape[2]
+        kn = knew_ref[0, 0]                              # [1, hsz]
         vn = vnew_ref[0, 0]
         if quant:
             kn, ks_new = _quantize_row(kn)               # int8-valued f32
             vn, vs_new = _quantize_row(vn)
-            ksrow_out_ref[0, 0, 0] = jnp.where(owner, ks_new,
-                                               ksrow_in_ref[0, 0, 0])
-            vsrow_out_ref[0, 0, 0] = jnp.where(owner, vs_new,
-                                               vsrow_in_ref[0, 0, 0])
-        krow_out_ref[0, 0, 0] = jnp.where(
-            owner, kn.astype(krow_out_ref.dtype), krow_in_ref[0, 0, 0])
-        vrow_out_ref[0, 0, 0] = jnp.where(
-            owner, vn.astype(vrow_out_ref.dtype), vrow_in_ref[0, 0, 0])
+            lane = jax.lax.broadcasted_iota(jnp.int32, (rw,), 0)
+            srow_hit = jnp.logical_and(owner, lane == j_new % rw)
+            ksrow_out_ref[0, 0] = jnp.where(srow_hit, ks_new,
+                                            ksrow_in_ref[0, 0])
+            vsrow_out_ref[0, 0] = jnp.where(srow_hit, vs_new,
+                                            vsrow_in_ref[0, 0])
+        wrows = jax.lax.broadcasted_iota(jnp.int32, (rw, 1), 0)
+        whit = jnp.logical_and(owner, wrows == j_new % rw)
+        krow_out_ref[0, 0] = jnp.where(
+            whit, kn.astype(krow_out_ref.dtype), krow_in_ref[0, 0])
+        vrow_out_ref[0, 0] = jnp.where(
+            whit, vn.astype(vrow_out_ref.dtype), vrow_in_ref[0, 0])
 
     def _compute():
         kraw = k_ref[0, 0]                               # [bs, hsz] cache dt
@@ -357,8 +379,8 @@ def _decode_kernel(meta_ref, tl_ref, *refs, scale: float,
             local = j_new - phys * block_s
             rows = jax.lax.broadcasted_iota(jnp.int32, (block_s, 1), 0)
             hit = jnp.logical_and(owner, rows == local)
-            kraw = jnp.where(hit, kn[None, :].astype(kraw.dtype), kraw)
-            vraw = jnp.where(hit, vn[None, :].astype(vraw.dtype), vraw)
+            kraw = jnp.where(hit, kn.astype(kraw.dtype), kraw)
+            vraw = jnp.where(hit, vn.astype(vraw.dtype), vraw)
             if quant:
                 kscale = jnp.where(hit[:, 0], ks_new, kscale)
                 vscale = jnp.where(hit[:, 0], vs_new, vscale)
@@ -385,7 +407,7 @@ def _decode_kernel(meta_ref, tl_ref, *refs, scale: float,
             pos = ((j // rr_block) * kvp + rank) * rr_block + (j % rr_block)
         mask = jnp.logical_and(jj < s_true, pos < total_len)
         mask = jnp.logical_and(
-            mask, jnp.where(window > 0, pos >= total_len - window, True))
+            mask, jnp.logical_or(window <= 0, pos >= total_len - window))
 
         s = jnp.where(mask, s, NEG_INF)
 
@@ -410,7 +432,7 @@ def _decode_kernel(meta_ref, tl_ref, *refs, scale: float,
         l = l_ref[...]
         denom = jnp.maximum(l, 1e-37)
         o_ref[0, 0] = jnp.where(l > 0, acc_ref[...] / denom, 0.0).astype(o_ref.dtype)
-        lse = jnp.where(l[:, 0] > 0, m_ref[:, 0] + jnp.log(denom[:, 0]), NEG_INF)
+        lse = jnp.where(l > 0, m_ref[...] + jnp.log(denom), NEG_INF)  # [Qp, 1]
         lse_ref[0, 0] = lse.astype(jnp.float32)
 
 
@@ -419,7 +441,7 @@ def flash_decode_kernel(q, k, v, meta, tl, *, scale: float, kvp: int,
                         contiguous: bool = False, kscale=None, vscale=None,
                         k_new=None, v_new=None, prune: bool = True,
                         block_tables=None, sfx_start=None, init_state=None,
-                        interpret: bool = True):
+                        interpret: bool):
     """Raw pallas_call.  Shapes must already be padded/blocked (see ops.py).
 
     q: [B, Kh, Qp, hsz]; k, v: [B, Kh, S_pad, hsz]; meta: [3] int32
@@ -480,6 +502,7 @@ def flash_decode_kernel(q, k, v, meta, tl, *, scale: float, kvp: int,
         assert s_pad % block_s == 0
         n_blocks = s_pad // block_s
     assert qp % 8 == 0
+    rw = append_rows(block_s)
 
     grid = (b, kh, n_blocks)
     kernel = functools.partial(
@@ -503,8 +526,8 @@ def flash_decode_kernel(q, k, v, meta, tl, *, scale: float, kvp: int,
         args += (sfx_start,)
         in_specs += [
             pl.BlockSpec((1, 1, qp, hsz), q_idx),
-            pl.BlockSpec((1, 1, qp), idx["lse"]),
-            pl.BlockSpec((1, 1, qp), idx["lse"]),
+            pl.BlockSpec((1, 1, qp, 1), idx["lse"]),
+            pl.BlockSpec((1, 1, qp, 1), idx["lse"]),
         ]
     in_specs += [
         pl.BlockSpec((1, 1, qp, hsz), q_idx),
@@ -512,17 +535,18 @@ def flash_decode_kernel(q, k, v, meta, tl, *, scale: float, kvp: int,
         pl.BlockSpec((1, 1, block_s, hsz), kv_idx),
     ]
     if grouped:
-        args += (acc0.astype(jnp.float32), m0.astype(jnp.float32),
-                 l0.astype(jnp.float32), q, k, v)
+        args += (acc0.astype(jnp.float32),
+                 m0.astype(jnp.float32).reshape(b, kh, qp, 1),
+                 l0.astype(jnp.float32).reshape(b, kh, qp, 1), q, k, v)
     else:
         args += (q, k, v)
     out_specs = [
         pl.BlockSpec((1, 1, qp, hsz), q_idx),
-        pl.BlockSpec((1, 1, qp), idx["lse"]),
+        pl.BlockSpec((1, 1, qp, 1), idx["lse"]),
     ]
     out_shape = [
         jax.ShapeDtypeStruct((b, kh, qp, hsz), q.dtype),
-        jax.ShapeDtypeStruct((b, kh, qp), jnp.float32),
+        jax.ShapeDtypeStruct((b, kh, qp, 1), jnp.float32),
     ]
     aliases = {}
     # inputs are numbered including the scalar-prefetch args; paged mode
@@ -540,15 +564,16 @@ def flash_decode_kernel(q, k, v, meta, tl, *, scale: float, kvp: int,
         args += (kscale.astype(jnp.float32), vscale.astype(jnp.float32))
     if append:
         in_specs += [
-            pl.BlockSpec((1, 1, hsz), idx["new"]),
-            pl.BlockSpec((1, 1, hsz), idx["new"]),
-            pl.BlockSpec((1, 1, 1, hsz), row_idx),
-            pl.BlockSpec((1, 1, 1, hsz), row_idx),
+            pl.BlockSpec((1, 1, 1, hsz), idx["new"]),
+            pl.BlockSpec((1, 1, 1, hsz), idx["new"]),
+            pl.BlockSpec((1, 1, rw, hsz), row_idx),
+            pl.BlockSpec((1, 1, rw, hsz), row_idx),
         ]
-        args += (k_new, v_new, k, v)
+        args += (k_new.reshape(b, kh, 1, hsz), v_new.reshape(b, kh, 1, hsz),
+                 k, v)
         out_specs += [
-            pl.BlockSpec((1, 1, 1, hsz), row_idx),
-            pl.BlockSpec((1, 1, 1, hsz), row_idx),
+            pl.BlockSpec((1, 1, rw, hsz), row_idx),
+            pl.BlockSpec((1, 1, rw, hsz), row_idx),
         ]
         out_shape += [
             jax.ShapeDtypeStruct(k.shape, k.dtype),
@@ -559,13 +584,13 @@ def flash_decode_kernel(q, k, v, meta, tl, *, scale: float, kvp: int,
         aliases = {qoff + 1: 2, qoff + 2: 3}
         if quant:
             in_specs += [
-                pl.BlockSpec((1, 1, 1), srow_idx),
-                pl.BlockSpec((1, 1, 1), srow_idx),
+                pl.BlockSpec((1, 1, rw), srow_idx),
+                pl.BlockSpec((1, 1, rw), srow_idx),
             ]
             args += (kscale.astype(jnp.float32), vscale.astype(jnp.float32))
             out_specs += [
-                pl.BlockSpec((1, 1, 1), srow_idx),
-                pl.BlockSpec((1, 1, 1), srow_idx),
+                pl.BlockSpec((1, 1, rw), srow_idx),
+                pl.BlockSpec((1, 1, rw), srow_idx),
             ]
             out_shape += [
                 jax.ShapeDtypeStruct(kscale.shape, jnp.float32),
@@ -576,7 +601,7 @@ def flash_decode_kernel(q, k, v, meta, tl, *, scale: float, kvp: int,
             aliases = {qoff + 1: 2, qoff + 2: 3,
                        qoff + 3: 4, qoff + 4: 5}
 
-    return pl.pallas_call(
+    res = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=npre,
@@ -593,6 +618,7 @@ def flash_decode_kernel(q, k, v, meta, tl, *, scale: float, kvp: int,
         input_output_aliases=aliases,
         interpret=interpret,
     )(*args)
+    return (res[0], res[1].reshape(b, kh, qp)) + tuple(res[2:])
 
 
 def grouped_prefix_index_maps(*, n_blocks: int):
@@ -618,7 +644,7 @@ def grouped_prefix_index_maps(*, n_blocks: int):
         return (g, h, 0, 0)
 
     def ml_idx(g, h, s, *_):
-        return (g, h, 0)
+        return (g, h, 0, 0)
 
     return {"kv": kv_idx, "scale": scale_idx, "q": q_idx, "acc": q_idx,
             "ml": ml_idx}
@@ -677,7 +703,7 @@ def _prefix_kernel(meta_ref, gnp_ref, gtl_ref, gtab_ref, *refs, scale: float,
         tl_col = tl_rows[:, None]                        # [gm*qp, 1]
         mask = jnp.logical_and(jj < s_true, pos < tl_col)
         mask = jnp.logical_and(
-            mask, jnp.where(window > 0, pos >= tl_col - window, True))
+            mask, jnp.logical_or(window <= 0, pos >= tl_col - window))
 
         s = jnp.where(mask, s, NEG_INF)
 
@@ -695,13 +721,13 @@ def _prefix_kernel(meta_ref, gnp_ref, gtl_ref, gtab_ref, *refs, scale: float,
         # RAW online-softmax state — no normalization; the suffix pass
         # resumes from exactly these (acc, m, l) per member row.
         acc_out[0, 0] = acc_ref[...]
-        m_out[0, 0] = m_ref[:, 0]
-        l_out[0, 0] = l_ref[:, 0]
+        m_out[0, 0] = m_ref[...]
+        l_out[0, 0] = l_ref[...]
 
 
 def prefix_pass_kernel(q_stacked, k, v, meta, gnp, gtl, gtab, *, scale: float,
                        kvp: int, rr_block: int, block_s: int, s_true: int,
-                       kscale=None, vscale=None, interpret: bool = True):
+                       kscale=None, vscale=None, interpret: bool):
     """Raw pallas_call: shared-prefix pass of the grouped decode.
 
     q_stacked: [G, Kh, Gm*Qp, hsz] — requests sharing a prefix have their
@@ -749,7 +775,7 @@ def prefix_pass_kernel(q_stacked, k, v, meta, gnp, gtl, gtab, *, scale: float,
         ]
         args += (kscale.astype(jnp.float32), vscale.astype(jnp.float32))
 
-    return pl.pallas_call(
+    acc, m, l = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
@@ -757,8 +783,8 @@ def prefix_pass_kernel(q_stacked, k, v, meta, gnp, gtl, gtab, *, scale: float,
             in_specs=in_specs,
             out_specs=[
                 pl.BlockSpec((1, 1, rows, hsz), idx["acc"]),
-                pl.BlockSpec((1, 1, rows), idx["ml"]),
-                pl.BlockSpec((1, 1, rows), idx["ml"]),
+                pl.BlockSpec((1, 1, rows, 1), idx["ml"]),
+                pl.BlockSpec((1, 1, rows, 1), idx["ml"]),
             ],
             scratch_shapes=[
                 pltpu.VMEM((rows, hsz), jnp.float32),
@@ -768,8 +794,9 @@ def prefix_pass_kernel(q_stacked, k, v, meta, gnp, gtl, gtab, *, scale: float,
         ),
         out_shape=[
             jax.ShapeDtypeStruct((g, kh, rows, hsz), jnp.float32),
-            jax.ShapeDtypeStruct((g, kh, rows), jnp.float32),
-            jax.ShapeDtypeStruct((g, kh, rows), jnp.float32),
+            jax.ShapeDtypeStruct((g, kh, rows, 1), jnp.float32),
+            jax.ShapeDtypeStruct((g, kh, rows, 1), jnp.float32),
         ],
         interpret=interpret,
     )(*args)
+    return acc, m.reshape(g, kh, rows), l.reshape(g, kh, rows)

@@ -49,7 +49,7 @@ import numpy as np
 
 from repro.utils import round_up, pad_dim
 from repro.kernels.contract import KernelContract, Operand
-from repro.kernels.flash_decode.kernel import (_append_slot,
+from repro.kernels.flash_decode.kernel import (_append_slot, append_rows,
                                                decode_index_maps,
                                                flash_decode_kernel,
                                                grouped_prefix_index_maps,
@@ -63,7 +63,7 @@ from repro.kernels.flash_decode.kernel import (_append_slot,
                      "contiguous", "prune"))
 def flash_decode(q, k, v, total_len, rank, *, kvp: int = 1, rr_block: int = 16,
                  window=0, scale: float | None = None, block_s: int = 512,
-                 interpret: bool = True, contiguous: bool = False,
+                 interpret: bool, contiguous: bool = False,
                  slot_offset=0, kscale=None, vscale=None,
                  k_new=None, v_new=None, prune: bool = True,
                  block_tables=None, groups=None):
@@ -418,14 +418,15 @@ def decode_case_contract(case="rr-prune", *, b=2, qh=4, kh=2, hsz=8,
                 else (b, kh, s_pad, hsz))
     sc_shape = ((n_pool, kh, block_s) if paged else (b, kh, s_pad))
     pax = 0 if paged else None
+    rw = append_rows(block_s)
 
     operands = []
     if grouped:
         # the prefix pass's raw state precedes q (kernel arg order)
         operands += [
             Operand("acc0", (b, kh, qp, hsz), (1, 1, qp, hsz), idx["q"]),
-            Operand("m0", (b, kh, qp), (1, 1, qp), idx["lse"]),
-            Operand("l0", (b, kh, qp), (1, 1, qp), idx["lse"]),
+            Operand("m0", (b, kh, qp, 1), (1, 1, qp, 1), idx["lse"]),
+            Operand("l0", (b, kh, qp, 1), (1, 1, qp, 1), idx["lse"]),
         ]
     operands += [
         Operand("q", (b, kh, qp, hsz), (1, 1, qp, hsz), idx["q"]),
@@ -443,41 +444,42 @@ def decode_case_contract(case="rr-prune", *, b=2, qh=4, kh=2, hsz=8,
         ]
     if append:
         operands += [
-            Operand("k_new", (b, kh, hsz), (1, 1, hsz), idx["new"]),
-            Operand("v_new", (b, kh, hsz), (1, 1, hsz), idx["new"]),
-            Operand("k_row_in", kv_shape, (1, 1, 1, hsz), idx["row"],
+            Operand("k_new", (b, kh, 1, hsz), (1, 1, 1, hsz), idx["new"]),
+            Operand("v_new", (b, kh, 1, hsz), (1, 1, 1, hsz), idx["new"]),
+            Operand("k_row_in", kv_shape, (1, 1, rw, hsz), idx["row"],
                     paged_axis=pax),
-            Operand("v_row_in", kv_shape, (1, 1, 1, hsz), idx["row"],
+            Operand("v_row_in", kv_shape, (1, 1, rw, hsz), idx["row"],
                     paged_axis=pax),
         ]
         if quant:
             operands += [
-                Operand("kscale_row_in", sc_shape, (1, 1, 1), idx["srow"],
+                Operand("kscale_row_in", sc_shape, (1, 1, rw), idx["srow"],
                         paged_axis=pax),
-                Operand("vscale_row_in", sc_shape, (1, 1, 1), idx["srow"],
+                Operand("vscale_row_in", sc_shape, (1, 1, rw), idx["srow"],
                         paged_axis=pax),
             ]
     operands += [
         Operand("out", (b, kh, qp, hsz), (1, 1, qp, hsz), idx["q"],
                 kind="out"),
-        Operand("lse", (b, kh, qp), (1, 1, qp), idx["lse"], kind="out"),
+        Operand("lse", (b, kh, qp, 1), (1, 1, qp, 1), idx["lse"],
+                kind="out"),
     ]
     npre = (3 if paged else 2) + (1 if grouped else 0)
     qoff = npre + (3 if grouped else 0)
     aliases = {}
     if append:
         operands += [
-            Operand("k_row_out", kv_shape, (1, 1, 1, hsz), idx["row"],
+            Operand("k_row_out", kv_shape, (1, 1, rw, hsz), idx["row"],
                     kind="out", alias_of="k", paged_axis=pax),
-            Operand("v_row_out", kv_shape, (1, 1, 1, hsz), idx["row"],
+            Operand("v_row_out", kv_shape, (1, 1, rw, hsz), idx["row"],
                     kind="out", alias_of="v", paged_axis=pax),
         ]
         aliases = {qoff + 1: 2, qoff + 2: 3}
         if quant:
             operands += [
-                Operand("kscale_row_out", sc_shape, (1, 1, 1), idx["srow"],
+                Operand("kscale_row_out", sc_shape, (1, 1, rw), idx["srow"],
                         kind="out", alias_of="kscale", paged_axis=pax),
-                Operand("vscale_row_out", sc_shape, (1, 1, 1), idx["srow"],
+                Operand("vscale_row_out", sc_shape, (1, 1, rw), idx["srow"],
                         kind="out", alias_of="vscale", paged_axis=pax),
             ]
             aliases = {qoff + 1: 2, qoff + 2: 3, qoff + 3: 4, qoff + 4: 5}
@@ -505,10 +507,12 @@ def decode_case_contract(case="rr-prune", *, b=2, qh=4, kh=2, hsz=8,
                                         s_pad))
 
         def expected_row(bi, h, _j=j_new, _tbl=table):
+            # the (1, 1, rw, hsz) window block holding the appended row
             j = int(_j[bi])
             if _tbl is not None:
-                return (int(_tbl[bi, j // block_s]), h, j % block_s, 0)
-            return (bi, h, j, 0)
+                return (int(_tbl[bi, j // block_s]), h,
+                        (j % block_s) // rw, 0)
+            return (bi, h, j // rw, 0)
 
     return KernelContract(
         family="flash_decode", case=case, grid=(b, kh, n_blocks),
@@ -562,8 +566,10 @@ def prefix_case_contract(case="grouped-prefix", *, g=2, gm=2, kh=2, hsz=8,
     operands += [
         Operand("acc", (g, kh, rows, hsz), (1, 1, rows, hsz), idx["acc"],
                 kind="out"),
-        Operand("m", (g, kh, rows), (1, 1, rows), idx["ml"], kind="out"),
-        Operand("l", (g, kh, rows), (1, 1, rows), idx["ml"], kind="out"),
+        Operand("m", (g, kh, rows, 1), (1, 1, rows, 1), idx["ml"],
+                kind="out"),
+        Operand("l", (g, kh, rows, 1), (1, 1, rows, 1), idx["ml"],
+                kind="out"),
     ]
 
     def active(gi, h, s, _np=gnp):

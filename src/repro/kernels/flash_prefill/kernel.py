@@ -5,7 +5,8 @@ TPU mapping
   grid = (B, Kh, T/blk_q, S/blk_k)   — kv blocks innermost; online-softmax
                                        state (m, l, acc) lives in VMEM scratch
                                        and persists across the kv loop.
-  q block   (blk_q, G*hsz)  resident per (b, h, qi)
+  q block   (G, blk_q, hsz) resident per (b, h, qi); the G query heads of
+                            one kv head stack along rows (G*blk_q, hsz)
   k/v block (blk_k, hsz)    streamed HBM->VMEM
   out       written at the last kv step (full row normalized)
 
@@ -100,7 +101,8 @@ def prefill_index_maps(*, causal: bool, blk_q: int, blk_k: int, s_true: int,
 
       kv  streamed K/V blocks (1, 1, blk_k, hsz); skip-clamped, and
           table-indirected in paged mode
-      q   resident query / output blocks (constant along the kv axis)
+      q   resident query / output blocks (1, 1, G, blk_q, hsz) (constant
+          along the kv axis)
     """
 
     def kv_idx(b, h, qi, ki, meta_ref, len_ref, off_ref, *rest):
@@ -116,7 +118,7 @@ def prefill_index_maps(*, causal: bool, blk_q: int, blk_k: int, s_true: int,
         return (b, h, lg, 0)
 
     def q_idx(b, h, qi, ki, *_):
-        return (b, h, qi, 0)
+        return (b, h, 0, qi, 0)
 
     return {"kv": kv_idx, "q": q_idx}
 
@@ -152,18 +154,17 @@ def _prefill_kernel(meta_ref, len_ref, off_ref, *refs, scale: float,
         phys, active = ki, None
 
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32) * scale      # [blq, G*hsz]
+        q = q_ref[0, 0].astype(jnp.float32) * scale      # [G, blq, hsz]
         k = k_ref[0, 0].astype(jnp.float32)              # [blk, hsz]
         v = v_ref[0, 0].astype(jnp.float32)              # [blk, hsz]
 
-        qg = q.reshape(blk_q, g, hsz)
-        s = jax.lax.dot_general(qg.reshape(blk_q * g, hsz), k,
+        s = jax.lax.dot_general(q.reshape(g * blk_q, hsz), k,
                                 (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-        s = s.reshape(blk_q, g, blk_k)
+        s = s.reshape(g, blk_q, blk_k)
 
         qpos = q_offset + qi * blk_q \
-            + jax.lax.broadcasted_iota(jnp.int32, (blk_q, 1, 1), 0)
+            + jax.lax.broadcasted_iota(jnp.int32, (1, blk_q, 1), 1)
         kpos = phys * blk_k \
             + jax.lax.broadcasted_iota(jnp.int32, (1, 1, blk_k), 2)
         # true-capacity + per-request-length masks apply in every mode; the
@@ -172,12 +173,12 @@ def _prefill_kernel(meta_ref, len_ref, off_ref, *refs, scale: float,
         if causal:
             mask = jnp.logical_and(mask, kpos <= qpos)
         mask = jnp.logical_and(
-            mask, jnp.where(window > 0, kpos > qpos - window, True))
+            mask, jnp.logical_or(window <= 0, kpos > qpos - window))
         s = jnp.where(mask, s, NEG_INF)
 
-        s2 = s.reshape(blk_q * g, blk_k)
-        mask2 = jnp.broadcast_to(mask, (blk_q, g, blk_k)).reshape(
-            blk_q * g, blk_k)
+        s2 = s.reshape(g * blk_q, blk_k)
+        mask2 = jnp.broadcast_to(mask, (g, blk_q, blk_k)).reshape(
+            g * blk_q, blk_k)
         m_prev = m_ref[...]
         m_new = jnp.maximum(m_prev, jnp.max(s2, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
@@ -199,16 +200,16 @@ def _prefill_kernel(meta_ref, len_ref, off_ref, *refs, scale: float,
         l = l_ref[...]
         denom = jnp.maximum(l, 1e-37)
         out = jnp.where(l > 0, acc_ref[...] / denom, 0.0)
-        o_ref[0, 0] = out.reshape(blk_q, g * hsz).astype(o_ref.dtype)
+        o_ref[0, 0] = out.reshape(g, blk_q, hsz).astype(o_ref.dtype)
 
 
 def flash_prefill_kernel(q, k, v, meta, lens, offs, *, scale: float,
                          causal: bool, blk_q: int, blk_k: int, s_true: int,
                          prune: bool = True, block_tables=None,
-                         interpret: bool = True):
+                         interpret: bool):
     """Raw pallas_call.  Shapes must already be padded/blocked (see ops.py).
 
-    q [B, Kh, T_pad, G*hsz]; k, v [B, Kh, S_pad, hsz]; meta [1] int32
+    q [B, Kh, G, T_pad, hsz]; k, v [B, Kh, S_pad, hsz]; meta [1] int32
     (window,); lens [B] int32 per-request valid KV lengths; offs [B] int32
     per-request q_offset (ragged chunk packing);
     s_true: unpadded S (slots >= s_true are masked); prune: skip (don't
@@ -220,11 +221,9 @@ def flash_prefill_kernel(q, k, v, meta, lens, offs, *, scale: float,
     ``logical`` is the fixed layout's (possibly skip-clamped) kv-block id.
     All masking runs on logical positions, so paged == fixed bit-exactly.
 
-    Returns out [B, Kh, T_pad, G*hsz] in q.dtype.
+    Returns out [B, Kh, G, T_pad, hsz] in q.dtype.
     """
-    b, kh, t, ghsz = q.shape
-    hsz = k.shape[3]
-    g = ghsz // hsz
+    b, kh, g, t, hsz = q.shape
     paged = block_tables is not None
     if paged:
         assert k.shape[2] == blk_k, (k.shape, blk_k)
@@ -252,18 +251,18 @@ def flash_prefill_kernel(q, k, v, meta, lens, offs, *, scale: float,
             num_scalar_prefetch=4 if paged else 3,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, 1, blk_q, ghsz), q_idx),
+                pl.BlockSpec((1, 1, g, blk_q, hsz), q_idx),
                 pl.BlockSpec((1, 1, blk_k, hsz), kv_idx),
                 pl.BlockSpec((1, 1, blk_k, hsz), kv_idx),
             ],
-            out_specs=pl.BlockSpec((1, 1, blk_q, ghsz), q_idx),
+            out_specs=pl.BlockSpec((1, 1, g, blk_q, hsz), q_idx),
             scratch_shapes=[
                 pltpu.VMEM((blk_q * g, hsz), jnp.float32),
                 pltpu.VMEM((blk_q * g, 1), jnp.float32),
                 pltpu.VMEM((blk_q * g, 1), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((b, kh, t, ghsz), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, kh, g, t, hsz), q.dtype),
         interpret=interpret,
     )(*((meta, lens, offs) + ((block_tables,) if paged else ())
         + (q, k, v)))

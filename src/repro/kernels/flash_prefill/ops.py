@@ -20,7 +20,7 @@ from repro.utils import round_up
 def flash_prefill(q, k, v, *, causal: bool = True, window=0, q_offset=0,
                   seq_lens=None, scale: float | None = None,
                   blk_q: int = 128, blk_k: int = 128, prune: bool = True,
-                  block_tables=None, interpret: bool = True):
+                  block_tables=None, interpret: bool):
     """Full-sequence attention via the Pallas flash-prefill kernel.
 
     The kernel-backed sibling of ``models/attention.chunked_attention`` —
@@ -75,10 +75,10 @@ def flash_prefill(q, k, v, *, causal: bool = True, window=0, q_offset=0,
     blk_q = min(blk_q, round_up(t, 8))
     t_pad = round_up(t, blk_q)
 
-    # [B,T,Kh,G,hsz] -> [B,Kh,T,G*hsz]
-    qg = q.reshape(b, t, kh, g, hsz).transpose(0, 2, 1, 3, 4).reshape(
-        b, kh, t, g * hsz)
-    qg = jnp.pad(qg, ((0, 0), (0, 0), (0, t_pad - t), (0, 0)))
+    # [B,T,Kh,G,hsz] -> [B,Kh,G,T,hsz]: the kernel stacks a block's G
+    # query heads along rows (a row-major merge of whole sublane tiles)
+    qg = q.reshape(b, t, kh, g, hsz).transpose(0, 2, 3, 1, 4)
+    qg = jnp.pad(qg, ((0, 0), (0, 0), (0, 0), (0, t_pad - t), (0, 0)))
     if paged:
         # sink-page table entries hold arbitrary data; only the per-request
         # length mask keeps them out of the reduction
@@ -111,7 +111,7 @@ def flash_prefill(q, k, v, *, causal: bool = True, window=0, q_offset=0,
                                causal=causal, blk_q=blk_q, blk_k=blk_k,
                                s_true=s, prune=prune, block_tables=tables,
                                interpret=interpret)
-    out = out[:, :, :t].reshape(b, kh, t, g, hsz).transpose(0, 2, 1, 3, 4)
+    out = out[:, :, :, :t].transpose(0, 3, 1, 2, 4)
     return out.reshape(b, t, qh, hsz)
 
 
@@ -248,13 +248,13 @@ def prefill_case_contract(case="causal-prune", *, b=2, kh=2, g=2, hsz=8,
                 else (b, kh, s_pad, hsz))
     pax = 0 if paged else None
     operands = [
-        Operand("q", (b, kh, t_pad, g * hsz), (1, 1, blk_q, g * hsz),
+        Operand("q", (b, kh, g, t_pad, hsz), (1, 1, g, blk_q, hsz),
                 idx["q"]),
         Operand("k", kv_shape, (1, 1, blk_k, hsz), idx["kv"],
                 streamed=True, paged_axis=pax),
         Operand("v", kv_shape, (1, 1, blk_k, hsz), idx["kv"],
                 streamed=True, paged_axis=pax),
-        Operand("out", (b, kh, t_pad, g * hsz), (1, 1, blk_q, g * hsz),
+        Operand("out", (b, kh, g, t_pad, hsz), (1, 1, g, blk_q, hsz),
                 idx["q"], kind="out"),
     ]
 

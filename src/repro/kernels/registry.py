@@ -27,7 +27,9 @@ The four registered families (see ``FAMILIES``):
 
 Selection is per-family via ``HelixConfig`` (core/sharding.py):
 ``attn_backend`` (flash_decode), ``prefill_backend`` (flash_prefill),
-``ssd_backend`` (ssd_prefill), ``matmul_backend`` (w8a16_matmul) — plumbed
+``ssd_backend`` (ssd_prefill), ``matmul_backend`` (w8a16_matmul); a field
+left unset takes ``default_backend`` (compiled Pallas on a TPU, ``ref``
+elsewhere) — plumbed
 through ``build_serve_step`` / ``make_prefill_step`` / ``make_train_step``,
 ``launch/serve.py`` / ``launch/train.py`` CLI flags and the serving engine.
 
@@ -207,6 +209,16 @@ def interpret_flag(backend: str) -> bool:
 def uses_kernel(backend: str) -> bool:
     """True when ``backend`` routes to the Pallas kernel (either mode)."""
     return backend in ("pallas-interpret", "pallas")
+
+
+def default_backend(family: str) -> str:
+    """The backend a family runs when nothing selects one: the compiled
+    Pallas kernel on a TPU, the ``ref`` oracle elsewhere (CPU tests keep
+    their meaning, and the interpreter is only ever chosen explicitly).
+    ``HelixConfig`` resolves its unset ``*_backend`` fields through here,
+    so this is the one place the platform picks kernels."""
+    validate(family, "ref")
+    return "pallas" if jax.devices()[0].platform == "tpu" else "ref"
 
 
 def available(family: str, backend: str) -> tuple[bool, str]:

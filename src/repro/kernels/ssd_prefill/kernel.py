@@ -95,7 +95,7 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, h0_ref, y_ref,
 
 
 def ssd_prefill_kernel(x, dt, a, bmat, cmat, d, h0, *, lc: int,
-                       interpret: bool = True):
+                       interpret: bool):
     """Pre-blocked shapes: x [B, nh, T, hd]; dt [B, nh, T, 1];
     a, d [nh, 1] f32; bmat, cmat [B, nh, T, ds]; h0 [B, nh, hd, ds] f32
     initial state.  T % lc == 0.

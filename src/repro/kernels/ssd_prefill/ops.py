@@ -12,7 +12,7 @@ from repro.utils import round_up
 
 @functools.partial(jax.jit, static_argnames=("lc", "interpret"))
 def ssd_prefill(x, dt, a, bmat, cmat, d, *, h0=None, lc: int = 64,
-                interpret: bool = True):
+                interpret: bool):
     """Mamba2 SSD prefill scan core via the Pallas kernel.
 
     The kernel-backed sibling of the ``models/ssm.ssd_chunked`` scan core —

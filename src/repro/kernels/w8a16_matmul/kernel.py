@@ -60,7 +60,7 @@ def _w8a16_kernel(x_ref, w_ref, s_ref, o_ref, acc_ref, *, bm, bn, bk):
         o_ref[...] = (acc_ref[...] * s_ref[...]).astype(o_ref.dtype)
 
 
-def w8a16_matmul_kernel(x, qw, scale, *, bm, bn, bk, interpret: bool = True):
+def w8a16_matmul_kernel(x, qw, scale, *, bm, bn, bk, interpret: bool):
     """x [M, K]; qw [K, N] int8; scale [1, N] f32 -> [M, N] (x.dtype).
 
     M % bm == K % bk == N % bn == 0 (ops.py pads).

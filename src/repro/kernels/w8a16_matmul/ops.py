@@ -13,7 +13,7 @@ from repro.utils import round_up
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
 def w8a16_matmul(x, qw, scale, *, bm: int = 128, bn: int = 128, bk: int = 256,
-                 interpret: bool = True):
+                 interpret: bool):
     """int8-weight x bf16/f32-activation matmul via the Pallas kernel.
 
     The w8a16_matmul *family* entry point the kernel-backend registry

@@ -58,6 +58,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
 import json
 import time
 
@@ -66,7 +67,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_config
-from repro.core.sharding import HelixConfig
+from repro.core.sharding import (HelixConfig, default_helix_config,
+                                 helix_param_specs, to_shardings)
 from repro.kernels.registry import BACKENDS, backend_table
 from repro.models.model_zoo import (build_serve_multistep, build_serve_step,
                                     chunked_prefill_supported,
@@ -81,7 +83,23 @@ from repro.serving.scheduler import POLICIES
 from repro.serving.workload import (TenantSpec, generate_trace, load_trace,
                                     parse_tenants, poisson_arrival_steps,
                                     requests_from_trace, trace_id)
-from repro.utils import make_mesh
+from repro.utils import enable_compile_cache, make_mesh
+
+
+def init_serving_params(cfg, seed: int, mesh=None, hx=None):
+    """Random weights for ``cfg`` from ``seed``, built by one compiled
+    program: the generator writes each weight in place instead of holding
+    eager temporaries (a full-width f32 model then peaks at its own size).
+    With ``mesh`` (and its ``hx``) each weight is generated directly into
+    its ``helix_param_specs`` sharding, so no device ever holds the whole
+    model.  Deterministic in (cfg, seed) — ``chip_smoke.py`` rebuilds the
+    served weights with it for its reference forward."""
+    key = jax.random.PRNGKey(seed)
+    out = None
+    if mesh is not None:
+        shapes = jax.eval_shape(functools.partial(init_params, cfg), key)
+        out = to_shardings(mesh, helix_param_specs(cfg, shapes, hx, mesh))
+    return jax.jit(init_params, static_argnums=0, out_shardings=out)(cfg, key)
 
 
 def serve_demo(arch: str, *, reduced: bool, n_requests: int, prompt_len: int,
@@ -165,11 +183,11 @@ def serve_demo(arch: str, *, reduced: bool, n_requests: int, prompt_len: int,
     cfg = get_config(arch)
     if reduced:
         cfg = cfg.reduced()
-    params = init_params(cfg, jax.random.PRNGKey(seed))
     if hx is None:
-        # single-device default; on a real mesh the caller supplies hx
-        hx = HelixConfig(kvp_axes=("data",) if mesh is None else (),
-                         tpa_axis=None)
+        # the paper's split of the mesh (KVP over every non-'model' axis);
+        # on the default 1x1 mesh that is KVP=1 over a size-1 'data' axis
+        hx = (HelixConfig(kvp_axes=("data",), tpa_axis=None) if mesh is None
+              else default_helix_config(cfg, mesh))
     overrides = {k: v for k, v in [("attn_backend", attn_backend),
                                    ("prefill_backend", prefill_backend),
                                    ("ssd_backend", ssd_backend),
@@ -187,6 +205,7 @@ def serve_demo(arch: str, *, reduced: bool, n_requests: int, prompt_len: int,
     if mesh is None:
         # single-device: 1x1 trivial mesh keeps one code path
         mesh = make_mesh((1, 1), ("data", "model"))
+    params = init_serving_params(cfg, seed, mesh, hx)
     sp = None
     if sampling is not None:
         sp = SamplingParams(kind=sampling, temperature=temperature,
@@ -243,7 +262,7 @@ def serve_demo(arch: str, *, reduced: bool, n_requests: int, prompt_len: int,
                           slo_ttl_s=(slo_ttl_ms / 1e3) if slo_ttl_ms else None,
                           clock=virtual_clock or time.monotonic,
                           sampling=sp, decode_window=decode_window,
-                          serve_multistep=multistep)
+                          serve_multistep=multistep, mesh=mesh)
     log(f"[serve] backends: {engine.describe_backends()}")
     rng = np.random.default_rng(seed)
     shared = rng.integers(0, cfg.vocab, shared_prefix_len).tolist()
@@ -343,8 +362,8 @@ def main():
                     help="print the TTFT/TTL/queue-wait summary JSON")
     ap.add_argument("--attn-backend", default=None, choices=BACKENDS,
                     help="flash_decode backend for decode attention "
-                         "(default: HelixConfig's, i.e. 'ref'; 'pallas' "
-                         "needs a TPU)")
+                         "(default: the platform's — compiled 'pallas' on a "
+                         "TPU, 'ref' elsewhere; 'pallas' needs a TPU)")
     ap.add_argument("--prefill-backend", default=None, choices=BACKENDS,
                     help="flash_prefill backend for prompt prefill")
     ap.add_argument("--ssd-backend", default=None, choices=BACKENDS,
@@ -438,6 +457,7 @@ def main():
         return
     if not args.arch:
         ap.error("--arch is required (or use --list-backends)")
+    enable_compile_cache()
     _, summary = serve_demo(
         args.arch, reduced=args.reduced, n_requests=args.requests,
         prompt_len=args.prompt_len, max_new=args.max_new,

@@ -258,7 +258,7 @@ def _kernel_prefill_fn(causal: bool, interpret: bool, chunk_q: int,
 def prefill_attention(q, k, v, *, causal: bool = True, window=0,
                       q_offset: int | jax.Array = 0, chunk_q: int = 512,
                       unroll: bool = False, backend: str = "ref",
-                      prune: bool = True, seq_lens=None):
+                      prune: bool = True, seq_lens=None, policy=None):
     """Full-sequence attention with kernel-backend selection.
 
     The prefill/train sibling of ``decode_attention``: ``backend`` routes the
@@ -272,7 +272,9 @@ def prefill_attention(q, k, v, *, causal: bool = True, window=0,
     kv blocks instead of masking them (bit-exact; see docs/kernels.md "Block
     pruning").  ``seq_lens`` ([B] int32, optional) masks kv positions
     ``>= seq_lens[b]`` per request (ragged continuous-batching prefill),
-    uniformly across backends.
+    uniformly across backends.  ``policy`` (the mesh sharding policy of a
+    GSPMD forward, models/transformer): the kernel runs on each device's
+    batch x head shard through ``policy.per_shard``.
 
       q [B, T, Qh, hsz]; k, v [B, S, Kh, hsz] -> out [B, T, Qh, hsz].
     """
@@ -287,8 +289,13 @@ def prefill_attention(q, k, v, *, causal: bool = True, window=0,
                             chunk_q, unroll, prune, ragged)
     lens = (jnp.asarray(seq_lens, jnp.int32) if ragged
             else jnp.zeros((), jnp.int32))
-    return fn(q, k, v, jnp.asarray(window, jnp.int32),
-              jnp.asarray(q_offset, jnp.int32), lens)
+    args = (q, k, v, jnp.asarray(window, jnp.int32),
+            jnp.asarray(q_offset, jnp.int32), lens)
+    if policy is None:
+        return fn(*args)
+    heads = ("dp", None, "tp", None)
+    rows = [("dp",) if a.ndim else () for a in args[3:]]
+    return policy.per_shard(fn, args, (heads, heads, heads, *rows), heads)
 
 
 # ------------------------------------------------------------- decode

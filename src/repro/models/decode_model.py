@@ -38,8 +38,8 @@ from repro.core.helix import (append_kv, append_kv_quant,
                               fuse_append_applicable, helix_attention)
 from repro.core.sharding import HelixConfig
 from repro.models import ssm as ssm_lib
-from repro.models.layers import (activation, apply_rope, rms_norm,
-                                 sinusoidal_at, softcap)
+from repro.models.layers import (activation, apply_rope, full_precision,
+                                 rms_norm, sinusoidal_at, softcap)
 from repro.models.moe import MoEParams, moe_ffn
 from repro.models.transformer import layer_windows
 
@@ -300,6 +300,7 @@ def _build_step_logits(cfg: ArchConfig, mesh: Mesh, hx: HelixConfig, *,
             x = x + ffn_phase(lp.get("ffn"), lp.get("moe"), h2)
         return x, new_caches
 
+    @full_precision
     def step_logits(params, state, tokens):
         """tokens [B] int32 -> (logits [B, padded_vocab], new_caches)."""
         tl = state["total_len"]

@@ -21,6 +21,7 @@ from repro.core.kvcache import cache_capacity
 from repro.core.sharding import HelixConfig, MeshPolicy, train_roles
 from repro.models.decode_model import (  # noqa: F401 re-export
     build_serve_multistep, build_serve_step)
+from repro.models.layers import full_precision
 from repro.models.transformer import (NO_POLICY, chunked_prefill_supported,
                                       forward, init_params, lm_loss)
 from repro.optim import AdamWConfig, adamw_init, adamw_update
@@ -122,6 +123,7 @@ def make_prefill_step(cfg: ArchConfig, mesh: Mesh | None, hx: HelixConfig,
     kvp = hx.kvp(mesh) if mesh else 1
     moe_groups = _dp_size(mesh) if cfg.moe else 1
 
+    @full_precision
     def prefill_step(params, batch):
         tokens = batch["tokens"]
         b, t = tokens.shape
@@ -201,6 +203,7 @@ def make_chunk_prefill_step(cfg: ArchConfig, mesh: Mesh | None,
         f"chunked prefill unsupported for {cfg.name} ({cfg.family})"
     policy = MeshPolicy(mesh, train_roles(mesh)) if mesh else NO_POLICY
 
+    @full_precision
     def chunk_step(params, tokens, buffers, q_offset):
         logits, extras = forward(
             cfg, params, tokens, return_cache=True, chunk_q=chunk_q,

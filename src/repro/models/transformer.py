@@ -39,6 +39,9 @@ class NoPolicy:
     def __call__(self, x, *axes):
         return x
 
+    def per_shard(self, fn, args, axes, out_axes):
+        return fn(*args)
+
 
 NO_POLICY = NoPolicy()
 
@@ -169,7 +172,7 @@ def _attn_block(cfg: ArchConfig, ap, h, *, layout: HeadLayout, window,
     out = prefill_attention(q, k, v, causal=causal, window=window,
                             chunk_q=chunk_q, q_offset=q_offset,
                             unroll=unroll, backend=attn_backend, prune=prune,
-                            seq_lens=seq_lens)
+                            seq_lens=seq_lens, policy=policy)
     out = out.reshape(b, t, layout.q_pad * hsz)
     proj = policy(out, "dp", None, "tp") @ wo
     return policy(proj, "dp", None, None), (k, v)

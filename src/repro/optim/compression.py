@@ -38,8 +38,7 @@ def compressed_pod_mean(grads, errors, axis_name: str = "pod"):
     psum the int8 payload in int32, dequantize.  Returns (mean_grads,
     new_errors).  Error feedback buffers live in the optimizer state.
     """
-    # jax.lax.axis_size is missing on older JAX; psum(1) is the portable form
-    npods = jax.lax.psum(1, axis_name)
+    npods = jax.lax.axis_size(axis_name)
 
     def one(g, e):
         gf = g.astype(jnp.float32) + e

@@ -96,6 +96,13 @@ class DecodeEngine:
     batch cap back once latency recovers.  Pair with a ``VirtualClock``
     metrics clock for deterministic, replayable latency summaries
     (scripts/trace_smoke.py).
+
+    ``mesh`` (the mesh the step functions were built on) places the
+    engine's device data: parameters by ``helix_param_specs`` and the
+    decode state by ``decode_state_specs`` (KV pool sharded over the KVP
+    axes), and pins the decode steps' state output to the same shardings,
+    so nothing is gathered onto one device or resharded between steps.
+    Without it everything stays on the default device.
     """
 
     def __init__(self, cfg: ArchConfig, params, serve_step: Callable,
@@ -117,7 +124,8 @@ class DecodeEngine:
                  governor: GovernorConfig | None = None,
                  sampling=None,
                  decode_window: int = 1,
-                 serve_multistep: Callable | None = None):
+                 serve_multistep: Callable | None = None,
+                 mesh=None):
         # ``hx`` (when given) wins over the bare rr_block arg so engine and
         # serve_step can't disagree on the round-robin block size.  kvp still
         # depends on the mesh (hx.kvp(mesh)), which the engine never sees —
@@ -139,7 +147,6 @@ class DecodeEngine:
         # re-quantizes the whole [H, V] matrix every decode step
         from repro.models.decode_model import prepare_decode_params
         self.params = prepare_decode_params(params, hx)
-        self.serve_step = jax.jit(serve_step)
         self.prefill_step = jax.jit(prefill_step)
         # on-device sampling (serving/sampling.py): ``sampling`` is the
         # engine-default SamplingParams; per-request policies ride
@@ -158,17 +165,6 @@ class DecodeEngine:
             raise ValueError("decode_window > 1 needs serve_multistep "
                              "(build one with build_serve_multistep)")
         self.decode_window = decode_window
-        if serve_multistep is not None:
-            # donate the decode state: the multi-GB KV pool must not be
-            # double-buffered across a window dispatch (CPU backends don't
-            # implement donation and warn, so gate on the platform)
-            if jax.default_backend() != "cpu":
-                self.serve_multistep = jax.jit(serve_multistep,
-                                               donate_argnums=(1,))
-            else:
-                self.serve_multistep = jax.jit(serve_multistep)
-        else:
-            self.serve_multistep = None
         # host-sync accounting for sync_stats(): blocking decode-loop
         # device->host transfers vs decode tokens emitted
         self.decode_syncs = 0
@@ -219,6 +215,7 @@ class DecodeEngine:
         self.state["total_len"] = jnp.zeros((max_batch,), jnp.int32)
         self.slots: list[Request | None] = [None] * max_batch
         self.cur_tokens = jnp.zeros((max_batch,), jnp.int32)
+        self._place(mesh, serve_step, serve_multistep)
 
         from repro.models.model_zoo import chunked_prefill_supported
         self.chunk_tokens = (chunk_tokens or None) \
@@ -291,6 +288,39 @@ class DecodeEngine:
             ttl_target_s=governor.ttl_target_s if governor else None)
         self._admission_retired: list[Request] = []
         self._frag_samples: list[float] = []
+
+    def _place(self, mesh, serve_step, serve_multistep) -> None:
+        """Place params/state on ``mesh`` (see the class doc) and jit the
+        decode steps; the windowed step donates the state, so the KV pool
+        is not double-buffered across a window dispatch (CPU backends
+        don't implement donation and warn, so gate on the platform)."""
+        state_out = None
+        if mesh is not None:
+            from jax.sharding import NamedSharding, PartitionSpec as P
+            from repro.core.kvcache import decode_state_specs
+            from repro.core.sharding import helix_param_specs, to_shardings
+            hx = self.hx if self.hx is not None else _default_hx(self.rr)
+            specs = decode_state_specs(self.cfg, hx, self.max_batch, mesh,
+                                       sampling=self.sampling is not None)
+            specs["total_len"] = P(None)
+            state_out = to_shardings(mesh, specs)
+            self.state = jax.device_put(self.state, state_out)
+            self.params = jax.device_put(self.params, to_shardings(
+                mesh, helix_param_specs(self.cfg, self.params, hx, mesh)))
+            rep = NamedSharding(mesh, P())
+            self.cur_tokens = jax.device_put(self.cur_tokens, rep)
+            self.serve_step = jax.jit(serve_step,
+                                      out_shardings=(rep, state_out))
+        else:
+            self.serve_step = jax.jit(serve_step)
+        self.serve_multistep = None
+        if serve_multistep is not None:
+            kw = {}
+            if state_out is not None:
+                kw["out_shardings"] = (rep, rep, state_out)
+            if jax.default_backend() != "cpu":
+                kw["donate_argnums"] = (1,)
+            self.serve_multistep = jax.jit(serve_multistep, **kw)
 
     # ------------------------------------------------------------- requests
     def submit(self, req: Request) -> None:

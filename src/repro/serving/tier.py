@@ -136,13 +136,10 @@ class HostPageStore:
         assert len(n_pages) == 1, f"ragged page axes: {n_pages}"
         n = n_pages.pop()
         assert n > 0, "zero-page snapshot"
-        if self._faults.draw("store_full"):
+        if self._faults.draw("store_full") or n > self.capacity:
             self.store_full += 1
             return False
         self.drop(key)
-        if n > self.capacity:
-            self.store_full += 1
-            return False
         while self.pages_used + n > self.capacity:
             old_key, old = next(iter(self._entries.items()))
             self._entries.pop(old_key)

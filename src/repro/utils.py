@@ -1,11 +1,13 @@
-"""Small shared utilities: padding, rounding, dtype helpers — plus the
-JAX-version compat shims (``make_mesh`` / ``set_mesh`` / ``shard_map``) every
-entrypoint must use instead of the raw jax APIs (the installed JAX may predate
-``jax.sharding.AxisType``, ``jax.set_mesh`` and ``jax.shard_map``)."""
+"""Small shared utilities: padding, rounding, dtype helpers, the mesh /
+shard_map entry points every module uses (thin calls of the JAX APIs, so
+axis types and the replication-check flag are set in one place), and the
+persistent compilation-cache placement shared by the serving CLI and
+``chip_smoke.py``."""
 from __future__ import annotations
 
-import inspect
 import math
+import os
+import pathlib
 
 import jax
 import jax.numpy as jnp
@@ -13,47 +15,48 @@ import jax.numpy as jnp
 NEG_INF = -1e30  # finite stand-in for -inf inside kernels (avoids NaN in exp/max)
 
 
-# ------------------------------------------------------- jax compat shims
+# ------------------------------------------------------------ mesh helpers
 def make_mesh(shape, axes, *, devices=None):
-    """``jax.make_mesh`` with Auto axis_types on JAX versions that take them.
-
-    Older JAX (< 0.6) has neither ``jax.sharding.AxisType`` nor the
-    ``axis_types=`` kwarg; every axis is implicitly Auto there, so dropping
-    the argument is semantics-preserving.
-    """
-    kwargs = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    params = inspect.signature(jax.make_mesh).parameters
-    if "axis_types" in params and hasattr(jax.sharding, "AxisType"):
-        kwargs["axis_types"] = (jax.sharding.AxisType.Auto,) * len(tuple(shape))
-    return jax.make_mesh(tuple(shape), tuple(axes), **kwargs)
+    """``jax.make_mesh`` with every axis ``Auto`` (GSPMD-propagated)."""
+    return jax.make_mesh(tuple(shape), tuple(axes), devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,)
+                         * len(tuple(shape)))
 
 
 def set_mesh(mesh):
-    """``jax.set_mesh`` context manager; on older JAX the Mesh object itself
-    is the context manager with the same scoping behaviour."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+    """``jax.set_mesh`` context manager."""
+    return jax.set_mesh(mesh)
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` across JAX versions.
+    """``jax.shard_map``."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
-    New JAX spells the replication-check kwarg ``check_vma``; the
-    experimental predecessor spells it ``check_rep``.  Semantics match.
-    The promotion to ``jax.shard_map`` and the kwarg rename were separate
-    changes, so the spelling is keyed off the signature, not the location.
-    """
-    if hasattr(jax, "shard_map"):
-        fn = jax.shard_map
-    else:
-        from jax.experimental.shard_map import shard_map as fn
-    kw = ("check_vma" if "check_vma" in inspect.signature(fn).parameters
-          else "check_rep")
-    return fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              **{kw: check_vma})
+
+# ------------------------------------------------------ compilation cache
+def compile_cache_dir() -> str:
+    """Directory of JAX's persistent compilation cache.
+
+    ``$JAX_COMPILATION_CACHE_DIR`` when it is set; otherwise the fixed
+    ``.jax_cache`` directory at the root of this checkout (git-ignored).
+    The path is part of every cache key, so it never depends on a temp
+    dir, pid or time — two runs from the same checkout share entries."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    return str(pathlib.Path(__file__).resolve().parents[2] / ".jax_cache")
+
+
+def enable_compile_cache() -> str:
+    """Point JAX's persistent compilation cache at ``compile_cache_dir()``
+    (and nowhere else), caching every compiled program however fast it
+    compiled.  Call before the first compile; returns the directory."""
+    path = compile_cache_dir()
+    jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    return path
 
 
 def cdiv(a: int, b: int) -> int:

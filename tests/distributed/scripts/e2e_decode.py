@@ -49,4 +49,24 @@ for arch in ["granite-3-2b", "gemma3-12b", "granite-moe-1b-a400m",
         err = np.abs(g - w).max()
         assert err < 2e-3, (arch, name, err)
     print(f"{arch:24s} prefill+2 decode steps == forward  OK")
+
+# ---- kernel-backed prefill on the mesh: GSPMD cannot partition a Pallas
+# kernel, so the forward runs flash_prefill on each (batch x head) shard
+# (MeshPolicy.per_shard); a batch the data axis does not divide (3 rows
+# over 4 shards) is replicated over it instead ----
+import dataclasses
+cfg = get_config("granite-3-2b").reduced()
+params = init_params(cfg, jax.random.PRNGKey(0))
+hx = dataclasses.replace(default_helix_config(cfg, mesh),
+                         prefill_backend="pallas-interpret")
+prefill = jax.jit(make_prefill_step(cfg, mesh, hx, s_cap=256))
+for B in (4, 3):
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (B, 24), 0, cfg.vocab)
+    with set_mesh(mesh):
+        last_logits, _ = prefill(params, {"tokens": tokens})
+    ref_logits, _ = forward(cfg, params, tokens, tp_width=1)
+    err = np.abs(np.asarray(last_logits, np.float32)[:, :cfg.vocab]
+                 - np.asarray(ref_logits, np.float32)[:, -1, :cfg.vocab]).max()
+    assert err < 2e-3, (B, err)
+    print(f"pallas-interpret prefill on the (4, 2) mesh, B={B} == forward  OK")
 print("ALL OK")

@@ -167,8 +167,12 @@ print("paged fused KV-append == fixed (KVP=8 shard_map): OK")
 
 # ---- grouped shared-prefix decode == ungrouped through the KVP=8 shard_map ----
 # rows 0,1 map the same first physical page (a shared prefix in the pool);
-# the two-pass grouped kernel must be bit-identical to the ungrouped sweep
-# over the same tables, including windowed and fused-append modes.
+# the two-pass grouped kernel must match the ungrouped sweep over the same
+# tables, including windowed and fused-append modes: the same blocks in the
+# same order, but the prefix pass multiplies a group's stacked query rows in
+# one matmul, and XLA:CPU picks its dot kernel by row count, so outputs
+# agree to f32 rounding (observed <= 6e-8); appended caches bit for bit.
+GROUPED_TOL = dict(rtol=1e-6, atol=1e-6)
 tbl2_np = np.asarray(tbl).copy()
 tbl2_np[1, 0] = tbl2_np[0, 0]
 tbl2 = jnp.asarray(tbl2_np)
@@ -183,19 +187,24 @@ with set_mesh(mesh):
         og = jax.jit(lambda q, k, v, t, g, n: helix_attention(
             mesh, hxp, q, k, v, tls2, window=win, block_tables=t,
             groups=(g, n)))(q, pool_k, pool_v, tbl2, gid_g, gnp_g)
-        np.testing.assert_array_equal(np.asarray(og), np.asarray(ou))
+        np.testing.assert_allclose(np.asarray(og), np.asarray(ou),
+                                   **GROUPED_TOL)
     of, kf, vf = jax.jit(lambda q, k, v, kn, vn, t: helix_attention(
         mesh, hxp, q, k, v, tls2 + 1, k_new=kn, v_new=vn, block_tables=t))(
             q, pool_k, pool_v, kn_p, vn_p, tbl2)
     og2, kg2, vg2 = jax.jit(lambda q, k, v, kn, vn, t, g, n: helix_attention(
         mesh, hxp, q, k, v, tls2 + 1, k_new=kn, v_new=vn, block_tables=t,
         groups=(g, n)))(q, pool_k, pool_v, kn_p, vn_p, tbl2, gid_g, gnp_g)
-np.testing.assert_array_equal(np.asarray(og2), np.asarray(of))
+np.testing.assert_allclose(np.asarray(og2), np.asarray(of), **GROUPED_TOL)
 np.testing.assert_array_equal(np.asarray(kg2), np.asarray(kf))
 np.testing.assert_array_equal(np.asarray(vg2), np.asarray(vf))
 print("grouped shared-prefix == ungrouped (KVP=8, windowed + fused append): OK")
 
 # ---- chunked prefill == one-shot prefill through the KVP=8 shard_map ----
+# tokens agree exactly; caches to f32 rounding of the K/V projection, whose
+# XLA:CPU dot kernel depends on the chunk's row count (as in
+# tests/serving/test_chunked_prefill_exact.py)
+CHUNK_TOL = dict(rtol=1e-5, atol=1e-5)
 from repro.configs import get_config
 from repro.models.model_zoo import (build_serve_step, finalize_chunked_prefill,
                                     init_prefill_buffers,
@@ -222,10 +231,10 @@ with set_mesh(mesh):
             pos += c
         st2 = finalize_chunked_prefill(cfg, hx_m, bufs, T, s_cap=CAP, kvp=8)
         assert int(nt[0, -1]) == tok1, (chunk, int(nt[0, -1]), tok1)
-        np.testing.assert_array_equal(np.asarray(st2["kcache"]),
-                                      np.asarray(st1["kcache"]))
-        np.testing.assert_array_equal(np.asarray(st2["vcache"]),
-                                      np.asarray(st1["vcache"]))
+        np.testing.assert_allclose(np.asarray(st2["kcache"]),
+                                   np.asarray(st1["kcache"]), **CHUNK_TOL)
+        np.testing.assert_allclose(np.asarray(st2["vcache"]),
+                                   np.asarray(st1["vcache"]), **CHUNK_TOL)
         # decode continuation agrees step for step (tokens + caches)
         serve = jax.jit(build_serve_step(cfg, mesh, hx_m))
         cur1 = cur2 = jnp.full((1,), tok1, jnp.int32)
@@ -234,8 +243,8 @@ with set_mesh(mesh):
             cur1, s1 = serve(params, s1, cur1)
             cur2, s2 = serve(params, s2, cur2)
             assert int(cur1[0]) == int(cur2[0])
-        np.testing.assert_array_equal(np.asarray(s2["kcache"]),
-                                      np.asarray(s1["kcache"]))
+        np.testing.assert_allclose(np.asarray(s2["kcache"]),
+                                   np.asarray(s1["kcache"]), **CHUNK_TOL)
 print("chunked prefill == one-shot (KVP=8 shard_map, chunk 17/T): OK")
 
 # ---- append_kv round-robin ----

@@ -61,9 +61,11 @@ def test_paged_equals_fixed(prune, window, tl_i):
     q, k, v, pk, pv, tables = make_case()
     tl = TLS[tl_i]
     of, lf = flash_decode(q, k, v, tl, 1, kvp=KVP, rr_block=RR,
-                          window=window, block_s=PS, prune=prune)
+                          window=window, block_s=PS, prune=prune,
+                          interpret=True)
     op, lp = flash_decode(q, pk, pv, tl, 1, kvp=KVP, rr_block=RR,
-                          window=window, prune=prune, block_tables=tables)
+                          window=window, prune=prune, block_tables=tables,
+                          interpret=True)
     np.testing.assert_array_equal(np.asarray(of), np.asarray(op))
     np.testing.assert_array_equal(np.asarray(lf), np.asarray(lp))
 
@@ -75,10 +77,12 @@ def test_paged_quant_equals_fixed(prune):
     pk8, pks = quant(pk); pv8, pvs = quant(pv)
     tl = TLS[0]
     of, _ = flash_decode(q, k8, v8, tl, 1, kvp=KVP, rr_block=RR, block_s=PS,
-                         kscale=ks, vscale=vs, prune=prune)
+                         kscale=ks, vscale=vs, prune=prune,
+                         interpret=True)
     op, _ = flash_decode(q, pk8, pv8, tl, 1, kvp=KVP, rr_block=RR,
                          kscale=pks, vscale=pvs, prune=prune,
-                         block_tables=tables)
+                         block_tables=tables,
+                         interpret=True)
     np.testing.assert_array_equal(np.asarray(of), np.asarray(op))
 
 
@@ -93,15 +97,19 @@ def test_paged_fused_append_equals_fixed(quantized):
         k8, ks = quant(k); v8, vs = quant(v)
         pk8, pks = quant(pk); pv8, pvs = quant(pv)
         rf = flash_decode(q, k8, v8, tl, 1, kvp=KVP, rr_block=RR, block_s=PS,
-                          kscale=ks, vscale=vs, k_new=kn, v_new=vn)
+                          kscale=ks, vscale=vs, k_new=kn, v_new=vn,
+                          interpret=True)
         rp = flash_decode(q, pk8, pv8, tl, 1, kvp=KVP, rr_block=RR,
                           kscale=pks, vscale=pvs, k_new=kn, v_new=vn,
-                          block_tables=tables)
+                          block_tables=tables,
+                          interpret=True)
     else:
         rf = flash_decode(q, k, v, tl, 1, kvp=KVP, rr_block=RR, block_s=PS,
-                          k_new=kn, v_new=vn)
+                          k_new=kn, v_new=vn,
+                          interpret=True)
         rp = flash_decode(q, pk, pv, tl, 1, kvp=KVP, rr_block=RR,
-                          k_new=kn, v_new=vn, block_tables=tables)
+                          k_new=kn, v_new=vn, block_tables=tables,
+                          interpret=True)
     np.testing.assert_array_equal(np.asarray(rf[0]), np.asarray(rp[0]))
     # appended pool planes reassemble into the appended fixed caches
     for fixed, pool in zip(rf[2:], rp[2:]):
@@ -153,7 +161,9 @@ def test_sink_entries_are_harmless():
     trimmed = np.asarray(tables).copy()
     trimmed[:, 1:] = 0                             # only page 0 allocated
     of, _ = flash_decode(q, k, v, short, 1, kvp=KVP, rr_block=RR,
-                         block_s=PS, prune=False)
+                         block_s=PS, prune=False,
+                         interpret=True)
     op, _ = flash_decode(q, pk, pv, short, 1, kvp=KVP, rr_block=RR,
-                         prune=False, block_tables=jnp.asarray(trimmed))
+                         prune=False, block_tables=jnp.asarray(trimmed),
+                         interpret=True)
     np.testing.assert_array_equal(np.asarray(of), np.asarray(op))

@@ -42,7 +42,8 @@ def test_per_row_q_offset_matches_solo(backend, window):
                                      chunk_q=8)
         return flash_prefill(qi, ki, vi, causal=True, window=window,
                              q_offset=off, seq_lens=lens_i,
-                             blk_q=8, blk_k=16)
+                             blk_q=8, blk_k=16,
+                             interpret=True)
 
     packed = attend(q, k, v, jnp.asarray(OFFS), lens)
     for i, off in enumerate(OFFS):
@@ -58,7 +59,8 @@ def test_ragged_ref_matches_kernel():
     a = chunked_attention(q, k, v, causal=True, q_offset=jnp.asarray(OFFS),
                           seq_lens=lens, chunk_q=8)
     b = flash_prefill(q, k, v, causal=True, q_offset=jnp.asarray(OFFS),
-                      seq_lens=lens, blk_q=8, blk_k=16)
+                      seq_lens=lens, blk_q=8, blk_k=16,
+                      interpret=True)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                rtol=2e-6, atol=2e-6)
 
@@ -86,11 +88,13 @@ def test_paged_prefill_equals_fixed(prune):
                 v[b, p * page:(p + 1) * page].transpose(1, 0, 2))
     lens = jnp.asarray([48, 64, 33], jnp.int32)
     fixed = flash_prefill(q, k, v, causal=True, q_offset=jnp.asarray(OFFS),
-                          seq_lens=lens, blk_q=8, blk_k=page, prune=prune)
+                          seq_lens=lens, blk_q=8, blk_k=page, prune=prune,
+                          interpret=True)
     paged = flash_prefill(q, pool_k, pool_v, causal=True,
                           q_offset=jnp.asarray(OFFS), seq_lens=lens,
                           blk_q=8, prune=prune,
-                          block_tables=jnp.asarray(tables))
+                          block_tables=jnp.asarray(tables),
+                          interpret=True)
     np.testing.assert_array_equal(np.asarray(fixed), np.asarray(paged))
     # accounting: indirection does not change the visited-block count
     af = flash_prefill_accounting(q, k, v, causal=True,
@@ -108,8 +112,9 @@ def test_scalar_offset_unchanged():
     """Scalar q_offset keeps the pre-ragged semantics bit-exactly (the
     broadcast [B] prefetch is the same value per row)."""
     q, k, v = make_case(4)
-    a = flash_prefill(q, k, v, causal=True, q_offset=7, blk_q=8, blk_k=16)
+    a = flash_prefill(q, k, v, causal=True, q_offset=7, blk_q=8, blk_k=16,
+                      interpret=True)
     b = flash_prefill(q, k, v, causal=True,
                       q_offset=jnp.full((B,), 7, jnp.int32),
-                      blk_q=8, blk_k=16)
+                      blk_q=8, blk_k=16, interpret=True)
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
