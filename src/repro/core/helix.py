@@ -293,23 +293,25 @@ def helix_attention(mesh: Mesh, hx: HelixConfig, q, kcache, vcache, total_len,
                             groups=grp_l)
         out, lse = res[0], res[1]
         bl = out.shape[0]
-        # single all-to-all over the query-head axis (§2.1.2): volume B×H/TPA,
-        # independent of S.
-        flat = out.reshape(bl, d_flat)
-        if d_pad != d_flat:
-            flat = jnp.pad(flat, ((0, 0), (0, d_pad - d_flat)))
-        frags = flat.reshape(bl, kvp, sl).transpose(1, 0, 2)  # [KVP, B, sl]
-        if kvp_axes:
-            frags = jax.lax.all_to_all(frags, kvp_axes, split_axis=0,
-                                       concat_axis=0, tiled=False)
-            lses = jax.lax.all_gather(lse, kvp_axes, axis=0, tiled=False)
-        else:
-            # KVP=1: nothing to exchange (a collective over no axes drops
-            # the rank axis); the local fragment is the whole combine input
-            lses = lse[None]
-        my_slice = jax.lax.dynamic_index_in_dim(
-            head_idx_table, rank, axis=0, keepdims=False)
-        combined = combine_fragments(frags, lses, my_slice)   # [B, sl]
+        with jax.named_scope("combine"):
+            # single all-to-all over the query-head axis (§2.1.2): volume
+            # B×H/TPA, independent of S.
+            flat = out.reshape(bl, d_flat)
+            if d_pad != d_flat:
+                flat = jnp.pad(flat, ((0, 0), (0, d_pad - d_flat)))
+            frags = flat.reshape(bl, kvp, sl).transpose(1, 0, 2)  # [KVP,B,sl]
+            if kvp_axes:
+                frags = jax.lax.all_to_all(frags, kvp_axes, split_axis=0,
+                                           concat_axis=0, tiled=False)
+                lses = jax.lax.all_gather(lse, kvp_axes, axis=0, tiled=False)
+            else:
+                # KVP=1: nothing to exchange (a collective over no axes
+                # drops the rank axis); the local fragment is the whole
+                # combine input
+                lses = lse[None]
+            my_slice = jax.lax.dynamic_index_in_dim(
+                head_idx_table, rank, axis=0, keepdims=False)
+            combined = combine_fragments(frags, lses, my_slice)  # [B, sl]
         if fused:
             # + appended local KV shards (and updated scales for int8)
             return (combined,) + tuple(res[2:])

@@ -617,6 +617,7 @@ def flash_decode_kernel(q, k, v, meta, tl, *, scale: float, kvp: int,
         out_shape=out_shape,
         input_output_aliases=aliases,
         interpret=interpret,
+        name="flash_decode",
     )(*args)
     return (res[0], res[1].reshape(b, kh, qp)) + tuple(res[2:])
 
@@ -798,5 +799,6 @@ def prefix_pass_kernel(q_stacked, k, v, meta, gnp, gtl, gtab, *, scale: float,
             jax.ShapeDtypeStruct((g, kh, rows, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_decode_prefix",
     )(*args)
     return acc, m.reshape(g, kh, rows), l.reshape(g, kh, rows)

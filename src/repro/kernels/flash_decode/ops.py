@@ -130,19 +130,21 @@ def flash_decode(q, k, v, total_len, rank, *, kvp: int = 1, rr_block: int = 16,
     else:
         s_cap = k.shape[2]
         block_s = min(block_s, round_up(s_cap, 128))
-        kp = pad_dim(k, 2, block_s)
-        vp = pad_dim(v, 2, block_s)
+        with jax.named_scope("kv_pool"):
+            kp = pad_dim(k, 2, block_s)
+            vp = pad_dim(v, 2, block_s)
         tables = None
     qp = round_up(g, 8)
 
     qg = q.reshape(b, kh, g, hsz)
     qg = pad_dim(qg, 2, qp)
     if kscale is not None:
-        kscale = kscale.astype(jnp.float32)
-        vscale = vscale.astype(jnp.float32)
-        if not paged:
-            kscale = pad_dim(kscale, 2, block_s)
-            vscale = pad_dim(vscale, 2, block_s)
+        with jax.named_scope("kv_pool"):
+            kscale = kscale.astype(jnp.float32)
+            vscale = vscale.astype(jnp.float32)
+            if not paged:
+                kscale = pad_dim(kscale, 2, block_s)
+                vscale = pad_dim(vscale, 2, block_s)
 
     meta = jnp.stack([jnp.asarray(rank, jnp.int32),
                       jnp.asarray(slot_offset, jnp.int32),
@@ -197,10 +199,12 @@ def flash_decode(q, k, v, total_len, rank, *, kvp: int = 1, rr_block: int = 16,
     if append:
         if paged:
             return (out, lse) + tuple(res[2:])
-        kc, vc = res[2][:, :, :s_cap], res[3][:, :, :s_cap]
-        if quant:
-            return out, lse, kc, vc, res[4][:, :, :s_cap], res[5][:, :, :s_cap]
-        return out, lse, kc, vc
+        with jax.named_scope("kv_pool"):
+            kc, vc = res[2][:, :, :s_cap], res[3][:, :, :s_cap]
+            if quant:
+                return (out, lse, kc, vc, res[4][:, :, :s_cap],
+                        res[5][:, :, :s_cap])
+            return out, lse, kc, vc
     return out, lse
 
 

@@ -264,5 +264,6 @@ def flash_prefill_kernel(q, k, v, meta, lens, offs, *, scale: float,
         ),
         out_shape=jax.ShapeDtypeStruct((b, kh, g, t, hsz), q.dtype),
         interpret=interpret,
+        name="flash_prefill",
     )(*((meta, lens, offs) + ((block_tables,) if paged else ())
         + (q, k, v)))

@@ -130,4 +130,5 @@ def ssd_prefill_kernel(x, dt, a, bmat, cmat, d, h0, *, lc: int,
             jax.ShapeDtypeStruct((b, nh, hd, ds), jnp.float32),
         ],
         interpret=interpret,
+        name="ssd_prefill",
     )(x, dt, a, bmat, cmat, d, h0)

@@ -82,4 +82,5 @@ def w8a16_matmul_kernel(x, qw, scale, *, bm, bn, bk, interpret: bool):
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
         interpret=interpret,
+        name="w8a16_matmul",
     )(x, qw, scale)

@@ -96,12 +96,13 @@ def _next_token(logits, state):
     otherwise the historical plain argmax.  Structural gating on
     ``sample_seed`` mirrors the grouped-decode ``group_id`` pattern: engines
     built without sampling never pay for (or trace) the sampler."""
-    if "sample_seed" in state:
-        from repro.serving.sampling import sample_tokens
-        return sample_tokens(logits, state["sample_temp"],
-                             state["sample_topk"], state["sample_topp"],
-                             state["sample_seed"], state["sample_idx"])
-    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    with jax.named_scope("sample"):
+        if "sample_seed" in state:
+            from repro.serving.sampling import sample_tokens
+            return sample_tokens(logits, state["sample_temp"],
+                                 state["sample_topk"], state["sample_topp"],
+                                 state["sample_seed"], state["sample_idx"])
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
 
 def _build_step_logits(cfg: ArchConfig, mesh: Mesh, hx: HelixConfig, *,
@@ -174,17 +175,18 @@ def _build_step_logits(cfg: ArchConfig, mesh: Mesh, hx: HelixConfig, *,
         # qkv_shard (§Perf, beyond-paper): weights over 'model', all-gather
         # the tiny activations — vs the paper's replicated per-rank QKV.
         qkv_ax = "model" if hx.qkv_shard and not tpa_ax else tpa_ax
-        q = cst(cst(h @ lp["wq"], None, qkv_ax),
-                None, tpa_ax).reshape(b, cfg.n_heads, cfg.hsz)
-        kn = cst(cst(h @ lp["wk"], None, qkv_ax),
-                 None, tpa_ax).reshape(b, cfg.n_kv_heads, cfg.hsz)
-        vn = cst(cst(h @ lp["wv"], None, qkv_ax),
-                 None, tpa_ax).reshape(b, cfg.n_kv_heads, cfg.hsz)
-        if cfg.use_rope:
-            pos = (tl_attn - 1)
-            pos = pos[..., None] if jnp.ndim(pos) else pos[None]  # [B,1]/[1]
-            q = apply_rope(q[:, None], pos, cfg.rope_theta)[:, 0]
-            kn = apply_rope(kn[:, None], pos, cfg.rope_theta)[:, 0]
+        with jax.named_scope("qkv"):
+            q = cst(cst(h @ lp["wq"], None, qkv_ax),
+                    None, tpa_ax).reshape(b, cfg.n_heads, cfg.hsz)
+            kn = cst(cst(h @ lp["wk"], None, qkv_ax),
+                     None, tpa_ax).reshape(b, cfg.n_kv_heads, cfg.hsz)
+            vn = cst(cst(h @ lp["wv"], None, qkv_ax),
+                     None, tpa_ax).reshape(b, cfg.n_kv_heads, cfg.hsz)
+            if cfg.use_rope:
+                pos = (tl_attn - 1)
+                pos = pos[..., None] if jnp.ndim(pos) else pos[None]
+                q = apply_rope(q[:, None], pos, cfg.rope_theta)[:, 0]
+                kn = apply_rope(kn[:, None], pos, cfg.rope_theta)[:, 0]
         chunks = hopb_chunks if b % hopb_chunks == 0 else 1
         if groups is not None:
             chunks = 1      # groups span the batch; chunks would split them
@@ -208,13 +210,15 @@ def _build_step_logits(cfg: ArchConfig, mesh: Mesh, hx: HelixConfig, *,
                     hopb_chunks=chunks, k_new=kn, v_new=vn,
                     block_tables=tables, groups=groups)
         else:
-            if kv8:
-                kc, vc, ks, vs = append_kv_quant(
-                    kc, vc, ks, vs, kn, vn, tl_attn, kvp=kvp,
-                    rr_block=hx.rr_block, block_tables=tables)
-            else:
-                kc, vc = append_kv(kc, vc, kn, vn, tl_attn, kvp=kvp,
-                                   rr_block=hx.rr_block, block_tables=tables)
+            with jax.named_scope("kv_pool"):
+                if kv8:
+                    kc, vc, ks, vs = append_kv_quant(
+                        kc, vc, ks, vs, kn, vn, tl_attn, kvp=kvp,
+                        rr_block=hx.rr_block, block_tables=tables)
+                else:
+                    kc, vc = append_kv(kc, vc, kn, vn, tl_attn, kvp=kvp,
+                                       rr_block=hx.rr_block,
+                                       block_tables=tables)
             out = helix_attention(mesh, hx, q, kc, vc, tl_attn, window=win,
                                   hopb_chunks=chunks,
                                   kscale=ks if kv8 else None,
@@ -270,21 +274,22 @@ def _build_step_logits(cfg: ArchConfig, mesh: Mesh, hx: HelixConfig, *,
 
     def layer_fn(x, lp, win, kc, vc, ks, vs, conv, sstate, xk, xv, tl_attn,
                  s_enc, tables, groups=None):
-        h = rms_norm(x, lp["ln1"])
         new_caches: dict[str, Any] = {}
-        if cfg.has_attention and cfg.has_ssm:          # hybrid (hymba)
-            a_out, kc, vc, ks, vs = attn_phase(lp["attn"], h, kc, vc, ks, vs,
-                                               tl_attn, win, tables, groups)
-            s_out, new_s = ssm_phase(lp["ssm"], h, conv, sstate)
-            x = x + 0.5 * (a_out + s_out)
-            new_caches.update(kcache=kc, vcache=vc, ssm_conv=new_s.conv,
-                              ssm_state=new_s.ssm)
-        elif cfg.has_attention:
-            a_out, kc, vc, ks, vs = attn_phase(lp["attn"], h, kc, vc, ks, vs,
-                                               tl_attn, win, tables, groups)
-            x = x + a_out
+        if cfg.has_attention:
+            with jax.named_scope("attn"):
+                h = rms_norm(x, lp["ln1"])
+                a_out, kc, vc, ks, vs = attn_phase(lp["attn"], h, kc, vc, ks,
+                                                   vs, tl_attn, win, tables,
+                                                   groups)
             new_caches.update(kcache=kc, vcache=vc)
+            if cfg.has_ssm:                            # hybrid (hymba)
+                s_out, new_s = ssm_phase(lp["ssm"], h, conv, sstate)
+                x = x + 0.5 * (a_out + s_out)
+                new_caches.update(ssm_conv=new_s.conv, ssm_state=new_s.ssm)
+            else:
+                x = x + a_out
         else:                                          # pure ssm (mamba2)
+            h = rms_norm(x, lp["ln1"])
             s_out, new_s = ssm_phase(lp["ssm"], h, conv, sstate)
             x = x + s_out
             new_caches.update(ssm_conv=new_s.conv, ssm_state=new_s.ssm)
@@ -296,8 +301,9 @@ def _build_step_logits(cfg: ArchConfig, mesh: Mesh, hx: HelixConfig, *,
             x = x + cross_phase(lp["xattn"], hxn, xk, xv, s_enc)
 
         if cfg.d_ff or cfg.moe:
-            h2 = rms_norm(x, lp["ln2"])
-            x = x + ffn_phase(lp.get("ffn"), lp.get("moe"), h2)
+            with jax.named_scope("ffn"):
+                h2 = rms_norm(x, lp["ln2"])
+                x = x + ffn_phase(lp.get("ffn"), lp.get("moe"), h2)
         return x, new_caches
 
     @full_precision
@@ -315,12 +321,13 @@ def _build_step_logits(cfg: ArchConfig, mesh: Mesh, hx: HelixConfig, *,
         groups = None
         if hx.grouped_decode and hx.paged_kv and "group_id" in state:
             groups = (state["group_id"], state["group_np"])
-        x = params["embed"][tokens]                     # [B, H]
-        x = cst(x, None, None)
-        if not cfg.use_rope:
-            pos = tl if jnp.ndim(tl) else tl[None]
-            pe = sinusoidal_at(pos.astype(jnp.float32), cfg.d_model)
-            x = x + pe.astype(x.dtype)
+        with jax.named_scope("embed"):
+            x = params["embed"][tokens]                 # [B, H]
+            x = cst(x, None, None)
+            if not cfg.use_rope:
+                pos = tl if jnp.ndim(tl) else tl[None]
+                pe = sinusoidal_at(pos.astype(jnp.float32), cfg.d_model)
+                x = x + pe.astype(x.dtype)
 
         L = cfg.n_layers
         s_enc = state.get("enc_len", 0) if cfg.is_encdec else 0
@@ -344,7 +351,8 @@ def _build_step_logits(cfg: ArchConfig, mesh: Mesh, hx: HelixConfig, *,
             xcur = carry
             outs = []
             for i in range(p):
-                leaf_i = jax.tree.map(lambda a: a[i], xs_p)
+                with jax.named_scope("kv_pool"):
+                    leaf_i = jax.tree.map(lambda a: a[i], xs_p)
                 lp, kc, vc, ks, vs, conv, sstate, xk, xv = leaf_i
                 xcur, nc = layer_fn(xcur, lp, win_static[i], kc, vc, ks, vs,
                                     conv, sstate, xk, xv, tl_attn, s_enc,
@@ -355,20 +363,22 @@ def _build_step_logits(cfg: ArchConfig, mesh: Mesh, hx: HelixConfig, *,
 
         x, new_caches = jax.lax.scan(body, x, xs,
                                      unroll=nper if unroll else 1)
-        new_caches = jax.tree.map(
-            lambda a: a.reshape(L, *a.shape[2:]), new_caches)
+        with jax.named_scope("kv_pool"):
+            new_caches = jax.tree.map(
+                lambda a: a.reshape(L, *a.shape[2:]), new_caches)
 
-        x = rms_norm(x, params["ln_f"])
-        head = params.get("lm_head")
-        if head is None:
-            head = params["embed"].T
-        logits = head_matmul(x, head, params)
-        logits = cst(logits, None, all_ax)
-        if cfg.softcap:
-            logits = softcap(logits, cfg.softcap)
-        vmask = jnp.where(jnp.arange(cfg.padded_vocab) < cfg.vocab,
-                          0.0, -1e30)
-        logits = logits + vmask.astype(logits.dtype)
+        with jax.named_scope("lm_head"):
+            x = rms_norm(x, params["ln_f"])
+            head = params.get("lm_head")
+            if head is None:
+                head = params["embed"].T
+            logits = head_matmul(x, head, params)
+            logits = cst(logits, None, all_ax)
+            if cfg.softcap:
+                logits = softcap(logits, cfg.softcap)
+            vmask = jnp.where(jnp.arange(cfg.padded_vocab) < cfg.vocab,
+                              0.0, -1e30)
+            logits = logits + vmask.astype(logits.dtype)
         return logits, new_caches
 
     return step_logits

@@ -211,8 +211,9 @@ def make_chunk_prefill_step(cfg: ArchConfig, mesh: Mesh | None,
             ssd_backend=hx.ssd_backend, prune_blocks=hx.prune_blocks,
             prefix_state=buffers, q_offset=q_offset, policy=policy,
             tp_width=mesh.shape["model"] if mesh else 1)
-        next_tokens = jnp.argmax(logits[:, :, :cfg.vocab],
-                                 axis=-1).astype(jnp.int32)
+        with jax.named_scope("sample"):
+            next_tokens = jnp.argmax(logits[:, :, :cfg.vocab],
+                                     axis=-1).astype(jnp.int32)
         new_buffers = {"kcache": extras["kcache"],
                        "vcache": extras["vcache"]}
         if return_last_logits:

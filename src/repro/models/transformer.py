@@ -139,34 +139,40 @@ def _attn_block(cfg: ArchConfig, ap, h, *, layout: HeadLayout, window,
     hsz = cfg.hsz
     wq = apply_q_layout(ap["wq"], layout, hsz)
     wo = apply_o_layout(ap["wo"], layout, hsz)
-    q = policy(h @ wq, "dp", None, "tp").reshape(b, t, layout.q_pad, hsz)
+    with jax.named_scope("qkv"):
+        q = policy(h @ wq, "dp", None, "tp").reshape(b, t, layout.q_pad, hsz)
     if kv_override is None:
-        wk = apply_kv_layout(ap["wk"], layout, hsz)
-        wv = apply_kv_layout(ap["wv"], layout, hsz)
-        k = policy(h @ wk, "dp", None, "tp").reshape(b, t, layout.kv_pad, hsz)
-        v = policy(h @ wv, "dp", None, "tp").reshape(b, t, layout.kv_pad, hsz)
         off = jnp.asarray(q_offset, jnp.int32)
         ragged = off.ndim == 1                 # [B] per-request offsets
-        if cfg.use_rope:
-            pos = (off[:, None] + jnp.arange(t)[None, :] if ragged
-                   else (jnp.arange(t) + off)[None, :])
-            q = apply_rope(q, pos, cfg.rope_theta)
-            k = apply_rope(k, pos, cfg.rope_theta)
+        with jax.named_scope("qkv"):
+            wk = apply_kv_layout(ap["wk"], layout, hsz)
+            wv = apply_kv_layout(ap["wv"], layout, hsz)
+            k = policy(h @ wk, "dp", None, "tp").reshape(b, t, layout.kv_pad,
+                                                         hsz)
+            v = policy(h @ wv, "dp", None, "tp").reshape(b, t, layout.kv_pad,
+                                                         hsz)
+            if cfg.use_rope:
+                pos = (off[:, None] + jnp.arange(t)[None, :] if ragged
+                       else (jnp.arange(t) + off)[None, :])
+                q = apply_rope(q, pos, cfg.rope_theta)
+                k = apply_rope(k, pos, cfg.rope_theta)
         if kv_buffer is not None:
-            kbuf, vbuf = kv_buffer
-            if ragged:
-                # ragged chunk packing: every request writes its chunk rows
-                # at its own prefill progress
-                upd = jax.vmap(lambda bu, nu, o: jax.lax.dynamic_update_slice(
-                    bu, nu, (o, 0, 0)))
-                kbuf = upd(kbuf, k.astype(kbuf.dtype), off)
-                vbuf = upd(vbuf, v.astype(vbuf.dtype), off)
-            else:
-                kbuf = jax.lax.dynamic_update_slice(
-                    kbuf, k.astype(kbuf.dtype), (0, off, 0, 0))
-                vbuf = jax.lax.dynamic_update_slice(
-                    vbuf, v.astype(vbuf.dtype), (0, off, 0, 0))
-            k, v = kbuf, vbuf
+            with jax.named_scope("kv_pool"):
+                kbuf, vbuf = kv_buffer
+                if ragged:
+                    # ragged chunk packing: every request writes its chunk
+                    # rows at its own prefill progress
+                    upd = jax.vmap(
+                        lambda bu, nu, o: jax.lax.dynamic_update_slice(
+                            bu, nu, (o, 0, 0)))
+                    kbuf = upd(kbuf, k.astype(kbuf.dtype), off)
+                    vbuf = upd(vbuf, v.astype(vbuf.dtype), off)
+                else:
+                    kbuf = jax.lax.dynamic_update_slice(
+                        kbuf, k.astype(kbuf.dtype), (0, off, 0, 0))
+                    vbuf = jax.lax.dynamic_update_slice(
+                        vbuf, v.astype(vbuf.dtype), (0, off, 0, 0))
+                k, v = kbuf, vbuf
     else:
         k, v = kv_override                     # cross-attn: precomputed enc KV
     out = prefill_attention(q, k, v, causal=causal, window=window,
@@ -201,27 +207,31 @@ def decoder_layer(cfg: ArchConfig, lp, x, *, layout, window, policy,
     ``_attn_block``): when given, the returned kcache/vcache are the
     *updated full-prefix buffers* instead of the chunk's own rows."""
     b, t, _ = x.shape
-    h = rms_norm(x, lp["ln1"])
     cache_kv = (jnp.zeros((b, t, 0, cfg.hsz), x.dtype),) * 2
     ssm_state = None
     if cfg.has_attention and cfg.has_ssm:                       # hybrid
-        a_out, cache_kv = _attn_block(cfg, lp["attn"], h, layout=layout,
-                                      window=window, policy=policy,
-                                      chunk_q=chunk_q, unroll=unroll,
-                                      attn_backend=attn_backend, prune=prune)
+        with jax.named_scope("attn"):
+            h = rms_norm(x, lp["ln1"])
+            a_out, cache_kv = _attn_block(cfg, lp["attn"], h, layout=layout,
+                                          window=window, policy=policy,
+                                          chunk_q=chunk_q, unroll=unroll,
+                                          attn_backend=attn_backend,
+                                          prune=prune)
         s_out, ssm_state = ssm_lib.ssd_chunked(
             ssm_lib.SSMParams(**lp["ssm"]), cfg, h, unroll=unroll,
             backend=ssd_backend)
         x = x + 0.5 * (a_out + s_out)
     elif cfg.has_attention:
-        a_out, cache_kv = _attn_block(cfg, lp["attn"], h, layout=layout,
-                                      window=window, policy=policy,
-                                      chunk_q=chunk_q, unroll=unroll,
-                                      attn_backend=attn_backend, prune=prune,
-                                      kv_buffer=kv_buffer, q_offset=q_offset,
-                                      seq_lens=seq_lens)
+        with jax.named_scope("attn"):
+            h = rms_norm(x, lp["ln1"])
+            a_out, cache_kv = _attn_block(
+                cfg, lp["attn"], h, layout=layout, window=window,
+                policy=policy, chunk_q=chunk_q, unroll=unroll,
+                attn_backend=attn_backend, prune=prune, kv_buffer=kv_buffer,
+                q_offset=q_offset, seq_lens=seq_lens)
         x = x + a_out
     else:                                                        # pure ssm
+        h = rms_norm(x, lp["ln1"])
         s_out, ssm_state = ssm_lib.ssd_chunked(
             ssm_lib.SSMParams(**lp["ssm"]), cfg, h, unroll=unroll,
             backend=ssd_backend)
@@ -243,18 +253,20 @@ def decoder_layer(cfg: ArchConfig, lp, x, *, layout, window, policy,
 
     aux = jnp.zeros((), jnp.float32)
     if cfg.d_ff or cfg.moe:
-        h2 = rms_norm(x, lp["ln2"])
-        delta = 0.0
-        if cfg.d_ff:
-            delta = _ffn_block(cfg, lp["ffn"], h2, policy)
-        if cfg.moe:
-            y, aux = moe_ffn(
-                MoEParams(**lp["moe"]), h2.reshape(b * t, -1),
-                cfg.moe, activation("silu"), groups=moe_groups,
-                c_disp=lambda v: policy(v, "dp", None, None, None),
-                c_exp=lambda v: policy(v, "pod", "ep", None, None))
-            delta = delta + policy(y.reshape(b, t, -1), "dp", None, None)
-        x = x + delta
+        with jax.named_scope("ffn"):
+            h2 = rms_norm(x, lp["ln2"])
+            delta = 0.0
+            if cfg.d_ff:
+                delta = _ffn_block(cfg, lp["ffn"], h2, policy)
+            if cfg.moe:
+                y, aux = moe_ffn(
+                    MoEParams(**lp["moe"]), h2.reshape(b * t, -1),
+                    cfg.moe, activation("silu"), groups=moe_groups,
+                    c_disp=lambda v: policy(v, "dp", None, None, None),
+                    c_exp=lambda v: policy(v, "pod", "ep", None, None))
+                delta = delta + policy(y.reshape(b, t, -1), "dp", None,
+                                       None)
+            x = x + delta
     return x, (cache_kv[0], cache_kv[1], ssm_state, aux)
 
 
@@ -316,8 +328,9 @@ def forward(cfg: ArchConfig, params, tokens, *, policy=NO_POLICY,
         assert chunked_prefill_supported(cfg), \
             f"chunked prefill unsupported for {cfg.name} ({cfg.family})"
         assert return_cache, "chunked prefill needs return_cache=True"
-    x = params["embed"][tokens]                                 # [B,T,H]
-    x = policy(x, "dp", None, None)
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens]                             # [B,T,H]
+        x = policy(x, "dp", None, None)
     if patch_embeds is not None:                                # vlm stub
         p = patch_embeds.shape[1]
         x = jnp.concatenate([patch_embeds.astype(x.dtype), x[:, p:]], axis=1)
@@ -362,15 +375,17 @@ def forward(cfg: ArchConfig, params, tokens, *, policy=NO_POLICY,
         body_fn, x, (params["layers"], windows, bufs),
         unroll=cfg.n_layers if unroll else 1)
 
-    x = rms_norm(x, params["ln_f"])
-    head = params.get("lm_head")
-    logits = x @ head if head is not None else x @ params["embed"].T
-    logits = policy(logits, "dp", None, "tp")
-    if cfg.softcap:
-        logits = softcap(logits, cfg.softcap)
-    # mask padded vocab rows so softmax/loss are exact
-    vmask = jnp.where(jnp.arange(cfg.padded_vocab) < cfg.vocab, 0.0, -1e30)
-    logits = logits + vmask.astype(logits.dtype)
+    with jax.named_scope("lm_head"):
+        x = rms_norm(x, params["ln_f"])
+        head = params.get("lm_head")
+        logits = x @ head if head is not None else x @ params["embed"].T
+        logits = policy(logits, "dp", None, "tp")
+        if cfg.softcap:
+            logits = softcap(logits, cfg.softcap)
+        # mask padded vocab rows so softmax/loss are exact
+        vmask = jnp.where(jnp.arange(cfg.padded_vocab) < cfg.vocab, 0.0,
+                          -1e30)
+        logits = logits + vmask.astype(logits.dtype)
 
     extras = {"aux_loss": jnp.sum(aux)}
     if return_cache:

@@ -40,6 +40,7 @@ from repro.core.kvcache import (cache_capacity, cache_to_pages,
                                 page_positions, quantize_decode_state,
                                 scatter_pool_pages)
 from repro.core.sharding import HelixConfig
+from repro.runtime.trace import span, step_span
 from repro.serving.governor import GovernorConfig, TTLGovernor
 from repro.serving.metrics import EngineMetrics, VirtualClock
 from repro.serving.pool import BlockAllocator
@@ -287,7 +288,9 @@ class DecodeEngine:
             clock=clock,
             ttl_target_s=governor.ttl_target_s if governor else None)
         self._admission_retired: list[Request] = []
-        self._frag_samples: list[float] = []
+        # running sum and count of the pool's fragmentation samples
+        self._frag_sum, self._frag_n = 0.0, 0
+        self._step_num = 0
 
     def _place(self, mesh, serve_step, serve_multistep) -> None:
         """Place params/state on ``mesh`` (see the class doc) and jit the
@@ -419,7 +422,8 @@ class DecodeEngine:
         if committed <= 0 or not phys:
             return False
         planes = gather_pool_pages(self.state, phys)
-        host = jax.device_get(planes)
+        with span("serve.readback"):
+            host = jax.device_get(planes)
         ok = self.store.put(f"spill:{req.rid}", host,
                             tokens=req.resume_tokens()[:committed])
         req.spill_key = f"spill:{req.rid}" if ok else None
@@ -434,17 +438,23 @@ class DecodeEngine:
         """One bounded engine iteration: admission, at most one prefill
         chunk, one decode step for every decoding slot, then the TTL
         governor's control decision (when armed).  Returns the requests
-        retired this step."""
-        self._tick(steps=1)
-        self._advance_restores()
-        finished = self._admission_retired + self._admit()
-        self._admission_retired = []
-        finished += self._prefill_chunk()
-        if self.decode_window > 1:
-            finished += self._decode_window()
-        else:
-            finished += self._decode_step()
-        self._govern()
+        retired this step.  Each phase is a profiler span
+        (runtime/trace.py) inside the step's ``serve.step``."""
+        self._step_num += 1
+        with step_span(self._step_num):
+            with span("serve.admit"):
+                self._tick(steps=1)
+                self._advance_restores()
+                finished = self._admission_retired + self._admit()
+                self._admission_retired = []
+            finished += self._prefill_chunk()
+            with span("serve.decode"):
+                if self.decode_window > 1:
+                    finished += self._decode_window()
+                else:
+                    finished += self._decode_step()
+            with span("serve.govern"):
+                self._govern()
         return finished
 
     def _tick(self, **work) -> None:
@@ -536,7 +546,8 @@ class DecodeEngine:
             else:
                 retired += self._oneshot_prefill(req, slot, defer=deferred)
         if deferred:
-            vals = np.asarray(jnp.stack([d for _, _, d in deferred]))
+            with span("serve.readback"):
+                vals = np.asarray(jnp.stack([d for _, _, d in deferred]))
             for (req, slot, _), v in zip(deferred, vals):
                 retired += self._commit_first_token(req, slot, int(v))
         # cache-pressure rejections retire without ever holding a slot
@@ -699,8 +710,9 @@ class DecodeEngine:
         even on kv8 engines (whose pool pages quantize per row, so the
         shared physical pages are also byte-identical to what the sharer
         would have written)."""
-        kv = (np.asarray(req.buffers["kcache"][:, 0, :t]),
-              np.asarray(req.buffers["vcache"][:, 0, :t]))
+        with span("serve.readback"):
+            kv = (np.asarray(req.buffers["kcache"][:, 0, :t]),
+                  np.asarray(req.buffers["vcache"][:, 0, :t]))
         self.prefix_index.register(list(req.prefill_tokens),
                                    list(self.pool.pages(req.rid)), kv)
 
@@ -734,60 +746,65 @@ class DecodeEngine:
         first = min(pre, key=lambda sr: sr[1].admit_seq)[1]
         c = width(first)
         group = [(s, r) for s, r in pre if width(r) == c]
-        self._tick(prefill_tokens=c * len(group))
-        for _, r in group:
-            if self._is_resume(r):
-                # a prefill chunk that reruns known context — zero on the
-                # host-tier happy path, counted on every fallback
-                self.metrics.bump("resume_reprefill_chunks")
-        tokens = jnp.asarray(
-            np.stack([r.prefill_tokens[r.prefill_pos:r.prefill_pos + c]
-                      for _, r in group]), jnp.int32)
-        tmax = max(len(r.prefill_tokens) for _, r in group)
+        with span("serve.prefill", rid=first.rid,
+                  tokens=c * len(group)):
+            self._tick(prefill_tokens=c * len(group))
+            for _, r in group:
+                if self._is_resume(r):
+                    # a prefill chunk that reruns known context — zero on
+                    # the host-tier happy path, counted on every fallback
+                    self.metrics.bump("resume_reprefill_chunks")
+            tokens = jnp.asarray(
+                np.stack([r.prefill_tokens[r.prefill_pos:r.prefill_pos + c]
+                          for _, r in group]), jnp.int32)
+            tmax = max(len(r.prefill_tokens) for _, r in group)
 
-        def padbuf(a):
-            pad = tmax - a.shape[2]
-            if pad == 0:
-                return a
-            width_ = [(0, 0)] * a.ndim
-            width_[2] = (0, pad)
-            return jnp.pad(a, width_)
+            def padbuf(a):
+                pad = tmax - a.shape[2]
+                if pad == 0:
+                    return a
+                width_ = [(0, 0)] * a.ndim
+                width_[2] = (0, pad)
+                return jnp.pad(a, width_)
 
-        bufs = jax.tree.map(
-            lambda *leaves: jnp.concatenate([padbuf(a) for a in leaves],
-                                            axis=1),
-            *[r.buffers for _, r in group])
-        offs = jnp.asarray([r.prefill_pos for _, r in group], jnp.int32)
-        if self.sampling is not None:
-            # sampling engines build their chunk step with
-            # return_last_logits=True: the done rows' final-position logits
-            # feed the on-device first-token sampler
-            next_toks, last_logits, bufs = self.chunk_step(
-                self.params, tokens, bufs, offs)
-        else:
-            next_toks, bufs = self.chunk_step(self.params, tokens, bufs, offs)
-        finished = []
-        done = [r.prefill_pos + c >= len(r.prefill_tokens)
-                for _, r in group]
-        # one batched transfer for every request finishing this chunk
-        first_np = None
-        if any(done):
-            di = [i for i, d in enumerate(done) if d]
+            bufs = jax.tree.map(
+                lambda *leaves: jnp.concatenate([padbuf(a) for a in leaves],
+                                                axis=1),
+                *[r.buffers for _, r in group])
+            offs = jnp.asarray([r.prefill_pos for _, r in group], jnp.int32)
             if self.sampling is not None:
-                dev = self._first_token_dev(
-                    last_logits[jnp.asarray(di)],
-                    [group[i][1] for i in di])
+                # sampling engines build their chunk step with
+                # return_last_logits=True: the done rows' final-position
+                # logits feed the on-device first-token sampler
+                next_toks, last_logits, bufs = self.chunk_step(
+                    self.params, tokens, bufs, offs)
             else:
-                dev = next_toks[jnp.asarray(di), c - 1]
-            first_np = {i: v for i, v in zip(di, np.asarray(dev))}
-        for i, (slot, req) in enumerate(group):
-            t_i = len(req.prefill_tokens)
-            req.buffers = jax.tree.map(lambda a: a[:, i:i + 1, :t_i], bufs)
-            req.prefill_pos += c
-            if done[i]:
-                finished += self._finish_prefill(req, slot,
-                                                 int(first_np[i]))
-        return finished
+                next_toks, bufs = self.chunk_step(self.params, tokens, bufs,
+                                                  offs)
+            finished = []
+            done = [r.prefill_pos + c >= len(r.prefill_tokens)
+                    for _, r in group]
+            # one batched transfer for every request finishing this chunk
+            first_np = None
+            if any(done):
+                di = [i for i, d in enumerate(done) if d]
+                if self.sampling is not None:
+                    dev = self._first_token_dev(
+                        last_logits[jnp.asarray(di)],
+                        [group[i][1] for i in di])
+                else:
+                    dev = next_toks[jnp.asarray(di), c - 1]
+                with span("serve.readback"):
+                    first_np = {i: v for i, v in zip(di, np.asarray(dev))}
+            for i, (slot, req) in enumerate(group):
+                t_i = len(req.prefill_tokens)
+                req.buffers = jax.tree.map(lambda a: a[:, i:i + 1, :t_i],
+                                           bufs)
+                req.prefill_pos += c
+                if done[i]:
+                    finished += self._finish_prefill(req, slot,
+                                                     int(first_np[i]))
+            return finished
 
     def _finish_prefill(self, req: Request, slot: int,
                         first_token: int) -> list[Request]:
@@ -1063,28 +1080,37 @@ class DecodeEngine:
                   if r is not None and r.state == DECODE]
         if not active:
             return []
-        self._tick(decode_slots=len(active))
-        if self.paged and self.prefix_index is not None:
-            self._cow_guard(active)
-        if self.grouped:
-            self._set_groups(active)
-        next_tokens, self.state = self.serve_step(
-            self.params, self.state, self.cur_tokens)
-        self.cur_tokens = next_tokens
-        # serve_step advances total_len for every row; pin non-decoding
-        # slots back to 0.  (Not the prefilling request's committed length:
-        # its K/V still lives in the carry buffers, so a non-zero length
-        # would make every decode step stream that many garbage cache
-        # blocks for the slot.  Length 0 keeps the dead row O(1) and the
-        # finalize scatter installs the real total_len.)
-        idle = [i for i in range(self.max_batch) if i not in active]
-        if idle:
-            self.state["total_len"] = \
-                self.state["total_len"].at[jnp.asarray(idle)].set(0)
+        with span("serve.dispatch"):
+            self._tick(decode_slots=len(active))
+            if self.paged and self.prefix_index is not None:
+                self._cow_guard(active)
+            if self.grouped:
+                self._set_groups(active)
+            next_tokens, self.state = self.serve_step(
+                self.params, self.state, self.cur_tokens)
+            self.cur_tokens = next_tokens
+            # serve_step advances total_len for every row; pin non-decoding
+            # slots back to 0.  (Not the prefilling request's committed
+            # length: its K/V still lives in the carry buffers, so a
+            # non-zero length would make every decode step stream that many
+            # garbage cache blocks for the slot.  Length 0 keeps the dead
+            # row O(1) and the finalize scatter installs the real
+            # total_len.)
+            idle = [i for i in range(self.max_batch) if i not in active]
+            if idle:
+                self.state["total_len"] = \
+                    self.state["total_len"].at[jnp.asarray(idle)].set(0)
         # one batched device->host transfer per step (per-slot int() calls
         # would each block on the device queue — B syncs instead of 1)
-        toks_np = np.asarray(next_tokens)
+        with span("serve.readback"):
+            toks_np = np.asarray(next_tokens)
         self.decode_syncs += 1
+        with span("serve.replay"):
+            return self._replay_step(active, toks_np)
+
+    def _replay_step(self, active: list[int], toks_np) -> list[Request]:
+        """A decode step's tokens into their requests: forced catch-up,
+        emissions, retirements and growth, then the pool sample."""
         finished = []
         forced: list[tuple[int, int]] = []
         for i in active:
@@ -1157,52 +1183,64 @@ class DecodeEngine:
                   if r is not None and r.state == DECODE]
         if not active:
             return []
-        finished = []
-        budgets = np.zeros((self.max_batch,), np.int32)
-        wants = np.zeros((self.max_batch,), np.int32)
-        eos = np.full((self.max_batch,), -1, np.int32)
-        forced = np.zeros((self.max_batch, n), np.int32)
-        nforced = np.zeros((self.max_batch,), np.int32)
-        stepping = []
-        for i in active:
-            req = self.slots[i]
-            nf = min(len(req.forced_tokens or ()), n)
-            emit_max = max(req.max_new_tokens - len(req.out_tokens), 0)
-            want = min(n, nf + emit_max)
-            grant = self.sched.grow_for_window(i, want)
-            if self.paged and grant:
-                self._mirror_table(i)
-            if grant == 0:
-                # can't take a single step: the capacity retire the
-                # single-step engine's grow_for_next_token would have hit
-                finished.append(self._retire(req, i, "capacity"))
-                continue
-            budgets[i], wants[i] = grant, want
-            if req.eos_id is not None:
-                eos[i] = req.eos_id
-            if nf:
-                forced[i, :nf] = req.forced_tokens[:nf]
-                nforced[i] = nf
-            stepping.append(i)
-        if not stepping:
-            return finished
-        if self.paged and self.prefix_index is not None:
-            self._cow_guard(stepping)
-        t0 = time.monotonic()
-        out_block, cur, self.state = self.serve_multistep(
-            self.params, self.state, self.cur_tokens,
-            jnp.asarray(budgets), jnp.asarray(eos),
-            jnp.asarray(forced), jnp.asarray(nforced))
-        self.cur_tokens = cur
-        # kick off the D2H copy, overlap host bookkeeping with it, then
-        # block ONCE on the whole window's token block
-        if hasattr(out_block, "copy_to_host_async"):
-            out_block.copy_to_host_async()
-        if self.paged:
-            self._sample_pool()
-        toks_np = np.asarray(out_block)
+        with span("serve.dispatch"):
+            finished = []
+            budgets = np.zeros((self.max_batch,), np.int32)
+            wants = np.zeros((self.max_batch,), np.int32)
+            eos = np.full((self.max_batch,), -1, np.int32)
+            forced = np.zeros((self.max_batch, n), np.int32)
+            nforced = np.zeros((self.max_batch,), np.int32)
+            stepping = []
+            for i in active:
+                req = self.slots[i]
+                nf = min(len(req.forced_tokens or ()), n)
+                emit_max = max(req.max_new_tokens - len(req.out_tokens), 0)
+                want = min(n, nf + emit_max)
+                grant = self.sched.grow_for_window(i, want)
+                if self.paged and grant:
+                    self._mirror_table(i)
+                if grant == 0:
+                    # can't take a single step: the capacity retire the
+                    # single-step engine's grow_for_next_token would have hit
+                    finished.append(self._retire(req, i, "capacity"))
+                    continue
+                budgets[i], wants[i] = grant, want
+                if req.eos_id is not None:
+                    eos[i] = req.eos_id
+                if nf:
+                    forced[i, :nf] = req.forced_tokens[:nf]
+                    nforced[i] = nf
+                stepping.append(i)
+            if not stepping:
+                return finished
+            if self.paged and self.prefix_index is not None:
+                self._cow_guard(stepping)
+            t0 = time.monotonic()
+            out_block, cur, self.state = self.serve_multistep(
+                self.params, self.state, self.cur_tokens,
+                jnp.asarray(budgets), jnp.asarray(eos),
+                jnp.asarray(forced), jnp.asarray(nforced))
+            self.cur_tokens = cur
+            # kick off the D2H copy, overlap host bookkeeping with it, then
+            # block ONCE on the whole window's token block
+            if hasattr(out_block, "copy_to_host_async"):
+                out_block.copy_to_host_async()
+            if self.paged:
+                self._sample_pool()
+        with span("serve.readback"):
+            toks_np = np.asarray(out_block)
         self.decode_syncs += 1
         t1 = time.monotonic()
+        with span("serve.replay"):
+            return self._replay_window(stepping, budgets, wants, toks_np,
+                                       t0, t1, finished)
+
+    def _replay_window(self, stepping: list[int], budgets, wants, toks_np,
+                       t0: float, t1: float,
+                       finished: list[Request]) -> list[Request]:
+        """A window's ``[B, N]`` token block into its requests, step by
+        step (see ``_decode_window``); appends retirements to ``finished``
+        and returns it."""
         # j-major replay: the same scheduler/metrics/retirement events the
         # single-step engine would emit, in the same order.  TTL samples
         # get in-window timestamps — VirtualClock ticks per replayed step,
@@ -1268,8 +1306,8 @@ class DecodeEngine:
         if used == 0:
             return
         committed = sum(self.sched.slot_len)
-        self._frag_samples.append(
-            1.0 - committed / (used * self.block_s))
+        self._frag_sum += 1.0 - committed / (used * self.block_s)
+        self._frag_n += 1
 
     def pool_stats(self) -> dict[str, float]:
         """Paged-pool health for the serving bench: peak occupancy (peak
@@ -1289,8 +1327,7 @@ class DecodeEngine:
                     "pool_frag_mean": 0.0, "capacity_retired": cap_retired,
                     "prefix_hit_rate": 0.0, "pages_shared_peak": 0,
                     "store_evictions": 0}
-        frag = (float(np.mean(self._frag_samples))
-                if self._frag_samples else 0.0)
+        frag = self._frag_sum / self._frag_n if self._frag_n else 0.0
         return {"paged_kv": True,
                 "pool_occupancy_peak":
                     self.pool.peak_in_use / max(self.pool.capacity, 1),
@@ -1352,7 +1389,8 @@ class DecodeEngine:
         if committed <= 0 or not phys:
             return
         planes = gather_pool_pages(self.state, phys)
-        host = jax.device_get(planes)
+        with span("serve.readback"):
+            host = jax.device_get(planes)
         if self.store.put(f"session:{req.session_id}", host,
                           tokens=req.resume_tokens()[:committed]):
             self.metrics.bump("spills")
