@@ -105,7 +105,6 @@ def check_docstrings() -> None:
         ("repro.core.kvcache", "gather_pool_pages"),
         ("repro.core.kvcache", "scatter_pool_pages"),
         ("repro.core.helix", "paged_slot_of_position"),
-        ("repro.kernels.pruning", "table_block"),
         ("repro.kernels.pruning", "span_clamp"),
         ("repro.kernels.registry", "KernelFamily"),
         ("repro.kernels.registry", "backend_table"),

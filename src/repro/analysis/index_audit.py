@@ -278,6 +278,11 @@ def _check_alias_races(contract, ops_by_name, tables) -> list[Finding]:
         src = ops_by_name.get(op.alias_of)
         if src is None or contract.expected_row is None or wrong:
             continue
+        # every streamed read of the aliased array: the source operand, or
+        # all page slots reading its plane
+        reads = [o for o in contract.operands
+                 if o.name in tables and (o is src or (
+                     src.plane is not None and o.plane == src.plane))]
         clashes = []
         for group, slicer in _stream_groups(contract.grid, ax):
             wrow = tuple(int(x) for x in tables[op.name][slicer][0])
@@ -286,9 +291,10 @@ def _check_alias_races(contract, ops_by_name, tables) -> list[Finding]:
             if wrow == want:
                 continue        # matching windows handled by (c)
             for s in range(contract.grid[ax]):
-                rrow = tuple(int(x)
-                             for x in tables[src.name][slicer][s])
-                if _windows_overlap(wrow, op.block, rrow, src.block):
+                if any(_windows_overlap(
+                        wrow, op.block,
+                        tuple(int(x) for x in tables[r.name][slicer][s]),
+                        r.block) for r in reads):
                     clashes.append(_grid_coords(group, ax, s, ndim))
                     break
         if clashes:
@@ -296,8 +302,8 @@ def _check_alias_races(contract, ops_by_name, tables) -> list[Finding]:
                 check="alias.race", path=_path(contract),
                 symbol=_symbol(contract, op.name),
                 message=f"aliased write window overlaps same-step "
-                        f"{src.name} reads away from the append row at "
-                        f"{_fmt_steps(clashes)}"))
+                        f"{src.plane or src.name} reads away from the "
+                        f"append row at {_fmt_steps(clashes)}"))
     return findings
 
 

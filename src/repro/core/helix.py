@@ -126,7 +126,8 @@ def _local_attend(q, k, v, total_len, rank, *, kvp, rr_block, window,
     Pallas backends stream pages through the prefetched table, the ref
     backend gathers the pages into the equivalent dense local cache first
     (bit-exact — masked tail slots contribute exact zeros).
-    block_s: fixed-layout kernel S-block size (``HelixConfig.attn_block_s``).
+    block_s: kernel S-block size (``HelixConfig.attn_block_s``); the paged
+    kernel gathers the whole pages that fit it.
     groups: (group_id [B], group_np [B]) — grouped shared-prefix decode
     (Pallas paged mode); the ref backend *ignores* the grouping, which is
     exactly the oracle semantics (grouping must not change results).

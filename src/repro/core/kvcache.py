@@ -45,10 +45,11 @@ def cache_capacity(cfg_seq_len: int, kvp: int, rr_block: int) -> int:
 def page_positions(kvp: int, rr_block: int) -> int:
     """Global positions per pool page: the smallest legal page (one
     round-robin cycle, ``kvp * rr_block``) — each KVP rank then holds
-    ``rr_block`` rows of every page.  Matching the decode kernel's S-block
-    size (``HelixConfig.attn_block_s``) to ``rr_block`` aligns the paged
-    and fixed online-softmax block partitions, making the two layouts
-    bit-identical end to end."""
+    ``rr_block`` rows of every page.  The paged decode kernel's S-block is
+    the whole pages that fit ``HelixConfig.attn_block_s``; at a block size
+    that is a multiple of ``rr_block`` the paged and fixed online-softmax
+    block partitions align, making the two layouts bit-identical end to
+    end."""
     return kvp * rr_block
 
 

@@ -116,10 +116,11 @@ class HelixConfig:
     #   Bit-exact vs the fixed layout at the same attn_block_s partition;
     #   decode-state leaves gain `block_tables` [B, max_pages] int32.
     attn_block_s: int = 512              # flash_decode S-block size (kernel
-    #   tuning knob; clamped to the shard capacity).  In paged mode the
-    #   per-rank page rows (rr_block) take over as the block size; setting
-    #   attn_block_s == rr_block makes fixed and paged online-softmax block
-    #   partitions identical, hence bit-exact parity between the layouts.
+    #   tuning knob; clamped to the shard capacity).  In paged mode a block
+    #   is the whole per-rank pages that fit it (at least one, at most the
+    #   table), gathered through the block table: at an attn_block_s that is
+    #   a multiple of the page rows, fixed and paged online-softmax block
+    #   partitions are identical, hence bit-exact parity between the layouts.
     # --- per-family kernel backends (kernels/registry.py); None = the
     # platform default (registry.default_backend) ---
     attn_backend: str | None = None      # flash_decode (helix decode attn)

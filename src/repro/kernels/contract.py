@@ -34,7 +34,10 @@ class Operand:
     the DMA-elision check); ``alias_of`` names the input operand an output
     writes through (``input_output_aliases``); ``paged_axis`` is the array
     axis addressed through a block-table indirection, whose bounds
-    violations are reported as ``bounds.page`` rather than ``bounds.block``.
+    violations are reported as ``bounds.page`` rather than ``bounds.block``;
+    ``plane`` names the array several operands read (the paged decode
+    kernel passes each pool plane once per page slot), so an aliased write
+    is checked against every slot's reads of that plane.
     """
 
     name: str
@@ -45,6 +48,7 @@ class Operand:
     streamed: bool = False
     alias_of: str | None = None
     paged_axis: int | None = None
+    plane: str | None = None
 
     def grid_limits(self):
         """Number of valid blocks per array axis (ceil-div shape/block)."""

@@ -35,6 +35,10 @@ Helix execution path when ``HelixConfig.attn_backend`` selects it):
     compute.  Bit-exact vs ``prune=False``; per-request HBM traffic becomes
     O(valid_len) instead of O(S_cap).  ``flash_decode_accounting`` reports
     the resulting blocks/bytes per call.
+  * ``block_tables`` — the shared-pool paged layout: an S-block gathers
+    the whole pages that fit ``block_s``, all KV heads a grid step
+    (``kernel.paged_decode_kernel``); ``groups`` adds the grouped
+    shared-prefix decode on top.
 
 Padded S slots are masked in-kernel against the true capacity (prefetch-free:
 it is a static kernel parameter), so any S_cap works in both layouts.
@@ -50,9 +54,14 @@ import numpy as np
 from repro.utils import round_up, pad_dim
 from repro.kernels.contract import KernelContract, Operand
 from repro.kernels.flash_decode.kernel import (_append_slot, append_rows,
+                                               block_pages,
                                                decode_index_maps,
                                                flash_decode_kernel,
-                                               grouped_prefix_index_maps,
+                                               page_schedule,
+                                               paged_decode_kernel,
+                                               paged_index_maps,
+                                               paged_spans,
+                                               prefix_index_maps,
                                                prefix_pass_kernel,
                                                prune_block_range)
 
@@ -78,23 +87,26 @@ def flash_decode(q, k, v, total_len, rank, *, kvp: int = 1, rr_block: int = 16,
     operands are shared pool planes ``[n_pool, Kh, page_s, hsz]``
     (``kscale``/``vscale`` become ``[n_pool, Kh, page_s]``): request ``b``'s
     logical local slots ``[p*page_s, (p+1)*page_s)`` live in physical pool
-    page ``block_tables[b, p]``.  The kernel's S-block size is pinned to
-    ``page_s`` and the index_maps stream through the prefetched table
-    (bit-exact vs the fixed layout at the same block size; pruning, quant
-    and the fused append all compose).  Unallocated table entries should
-    point at the reserved sink page 0.
+    page ``block_tables[b, p]``.  One S-block is ``block_pages(block_s,
+    page_s, max_pages)`` whole pages, gathered through the prefetched table
+    by one page-slot input each, all KV heads a grid step
+    (kernel.paged_decode_kernel); bit-exact vs the fixed layout at
+    ``block_s`` = that many pages' rows (pruning, window, quant and the
+    fused append all compose).  Unallocated table entries should point at
+    the reserved sink page 0.
 
     Grouped shared-prefix decode (``groups`` — paged only): ``groups =
     (group_id [B], group_np [B])`` int32 marks requests whose block tables
     share their leading ``group_np`` physical pages (CoDec-style, arXiv
     2505.17694).  ``group_id`` is any stable representative (e.g. the
     lowest member's batch row); singletons use their own row with
-    ``group_np == 0``.  The call splits into two passes: a *prefix* pass
+    ``group_np == 0``.  Each shared span is rounded down to whole S-blocks,
+    and the call splits into two passes: a *prefix* pass
     (``prefix_pass_kernel``) stacks each group's Q rows and streams every
-    shared page **once per group**, emitting raw online-softmax state, and
-    the *suffix* pass resumes that state while its span clamp skips blocks
-    below ``group_np``.  Bit-exact with ``groups=None`` — same block order,
-    same masks — while prefix HBM reads drop by ~1/group_size.
+    shared block's pages **once per group**, emitting raw online-softmax
+    state, and the *suffix* pass resumes that state from the first unshared
+    block.  Bit-exact with ``groups=None`` — same blocks, same order, same
+    masks — while prefix HBM reads drop by ~1/group_size.
 
     Returns ``(out [B, Qh, hsz], lse [B, Qh] f32)``, plus the appended
     ``(kcache, vcache)`` when ``k_new``/``v_new`` engage the fused-append
@@ -122,9 +134,10 @@ def flash_decode(q, k, v, total_len, rank, *, kvp: int = 1, rr_block: int = 16,
         assert not contiguous, "paged mode excludes the contiguous layout"
         assert not (isinstance(slot_offset, int) and slot_offset != 0), \
             "paged mode excludes the cache-slice fast path"
-        # page size is the kernel block; logical capacity spans the table
-        block_s = k.shape[2]
-        s_cap = block_tables.shape[1] * block_s
+        # the logical capacity spans the table; an S-block is whole pages
+        page_s, max_pages = k.shape[2], block_tables.shape[1]
+        pages = block_pages(block_s, page_s, max_pages)
+        s_cap = max_pages * page_s
         kp, vp = k, v
         tables = jnp.asarray(block_tables, jnp.int32)
     else:
@@ -165,33 +178,40 @@ def flash_decode(q, k, v, total_len, rank, *, kvp: int = 1, rr_block: int = 16,
     if groups is not None:
         assert paged, "grouped decode requires paged mode"
         gid = jnp.asarray(groups[0], jnp.int32)
-        gnp_req = jnp.asarray(groups[1], jnp.int32)
+        # whole shared S-blocks: both passes then walk the ungrouped blocks
+        gnb_req = jnp.asarray(groups[1], jnp.int32) // pages
         # static worst case: B group rows x B member slots (every request a
         # singleton, or one group holding the whole batch); unused rows
-        # carry gnp == 0 / gtl == 0 and degenerate to the identity update
-        gnp = jnp.zeros((b,), jnp.int32).at[gid].max(gnp_req)
+        # carry gnb == 0 / gtl == 0 and degenerate to the identity update
+        gnb = jnp.zeros((b,), jnp.int32).at[gid].max(gnb_req)
         bidx = jnp.arange(b)
         same = gid[None, :] == gid[:, None]
         ms = jnp.sum(same & (bidx[None, :] < bidx[:, None]), axis=1)
         gtl = jnp.zeros((b, b), jnp.int32).at[gid, ms].set(tl)
-        # duplicate-index winner is irrelevant: only the leading gnp[g]
+        # duplicate-index winner is irrelevant: only the leading shared
         # entries are read, and members of a group share exactly those
         gtab = jnp.zeros((b, tables.shape[1]), jnp.int32).at[gid].set(tables)
         qs = jnp.zeros((b, kh, b, qp, hsz), qg.dtype).at[gid, :, ms].set(qg)
         acc_g, m_g, l_g = prefix_pass_kernel(
-            qs.reshape(b, kh, b * qp, hsz), kp, vp, meta, gnp, gtl, gtab,
-            scale=scale, kvp=kvp, rr_block=rr_block, block_s=block_s,
-            s_true=s_cap, kscale=kscale, vscale=vscale, interpret=interpret)
+            qs.reshape(b, kh, b * qp, hsz), kp, vp, meta, gnb, gtl, gtab,
+            scale=scale, kvp=kvp, rr_block=rr_block, pages=pages,
+            kscale=kscale, vscale=vscale, interpret=interpret)
         acc0 = acc_g.reshape(b, kh, b, qp, hsz)[gid, :, ms]
         m0 = m_g.reshape(b, kh, b, qp)[gid, :, ms]
         l0 = l_g.reshape(b, kh, b, qp)[gid, :, ms]
-        kw.update(sfx_start=gnp_req, init_state=(acc0, m0, l0))
+        kw.update(sfx_start=gnb_req, init_state=(acc0, m0, l0))
 
-    res = flash_decode_kernel(
-        qg, kp, vp, meta, tl, scale=scale, kvp=kvp, rr_block=rr_block,
-        block_s=block_s, s_true=s_cap, contiguous=contiguous,
-        kscale=kscale, vscale=vscale, prune=prune, block_tables=tables,
-        interpret=interpret, **kw)
+    if paged:
+        res = paged_decode_kernel(
+            qg, kp, vp, meta, tl, tables, scale=scale, kvp=kvp,
+            rr_block=rr_block, pages=pages, kscale=kscale, vscale=vscale,
+            prune=prune, interpret=interpret, **kw)
+    else:
+        res = flash_decode_kernel(
+            qg, kp, vp, meta, tl, scale=scale, kvp=kvp, rr_block=rr_block,
+            block_s=block_s, s_true=s_cap, contiguous=contiguous,
+            kscale=kscale, vscale=vscale, prune=prune, interpret=interpret,
+            **kw)
 
     out, lse = res[0], res[1]
     out = out[:, :, :g, :].reshape(b, qh, hsz)
@@ -216,25 +236,29 @@ def flash_decode_accounting(q, k, v, total_len, rank, *, kvp: int = 1,
                             groups=None, **_ignored):
     """Blocks/bytes the matching ``flash_decode`` call streams from HBM.
 
-    Replays the kernel's pruning ``index_map`` (``prune_block_range`` — the
-    same function the kernel clamps its K/V DMAs with) over the grid and
-    counts *distinct* block fetches: consecutive grid steps that reference
-    the same block are one DMA on TPU, which is exactly how pruning turns
-    masked blocks into elided reads.  ``prune=False`` reproduces the dense
-    sweep (every block of every (b, h) pair).
+    Fixed layout: replays the kernel's pruning ``index_map``
+    (``prune_block_range`` — the same function the kernel clamps its K/V
+    DMAs with) over the grid and counts *distinct* block fetches:
+    consecutive grid steps that reference the same block are one DMA on
+    TPU, which is exactly how pruning turns masked blocks into elided
+    reads.  ``prune=False`` reproduces the dense sweep (every block of
+    every (b, h) pair).
 
     Paged mode (``block_tables`` [B, max_pages]): ``k``/``v`` are pool
-    planes ``[n_pool, Kh, page_s, hsz]``; the replay walks the same logical
-    page ranges through the table — a request's pages are distinct physical
-    planes, so the distinct-fetch count (and the prune bound
-    ``<= ceil(valid_len/block_s) + 1`` per (b, h)) is unchanged by the
-    indirection, only ``block_s`` is pinned to the page size.
+    planes ``[n_pool, Kh, page_s, hsz]``.  Replays the paged blocking — a
+    ``(B, ceil(max_pages / P))`` grid of S-blocks of ``P =
+    block_pages(block_s, page_s, max_pages)`` pages, all heads a step —
+    through ``paged_spans`` and ``page_dma``: a page slot fetches at the
+    first grid step and whenever its pool page changes, so ``page_dmas``
+    counts the page copies the call issues (each one page of K and of V,
+    all heads, plus their scales with int8 pools) — how often the
+    mechanism engages.  ``blocks_visited`` counts live S-blocks (compute
+    steps), ``grid_steps`` all steps.
 
-    Grouped mode (``groups = (group_id [B], group_np [B])``): replays both
-    passes.  The prefix pass streams ``max(group_np_g, 1)`` pages per
-    *group* grid row (all B rows exist; memberless rows reference the
-    clamped sink page once), the suffix pass per request lifts the pruned
-    span's lower bound to ``group_np[b]`` — together they prove the
+    Grouped mode (``groups = (group_id [B], group_np [B])``, paged):
+    replays both passes.  Shared spans round down to whole S-blocks; the
+    prefix pass streams each group row's shared blocks once, the suffix
+    pass per request starts above them — together they prove the
     ~1/group_size prefix bytes-read reduction.  The split is reported via
     ``prefix_blocks``/``suffix_blocks`` (and ``prefix_bytes``/
     ``suffix_bytes``); ungrouped calls report ``prefix_blocks == 0``.
@@ -245,61 +269,42 @@ def flash_decode_accounting(q, k, v, total_len, rank, *, kvp: int = 1,
     Returns a dict:
 
       ``blocks_visited`` / ``blocks_total`` — distinct K/V block DMAs vs the
-      dense sweep, summed over the (B, Kh, S-blocks) grid;
+      dense sweep, summed over the (B, Kh, S-blocks) grid (fixed layout);
+      live vs all S-blocks of the (B, S-blocks) grid (paged);
       ``bytes_read`` / ``bytes_total`` — the corresponding K+V HBM bytes
       (+ dequant-scale bytes in int8 mode);
       ``prefix_blocks``/``suffix_blocks``, ``prefix_bytes``/
       ``suffix_bytes`` — the grouped two-pass split of ``blocks_visited``;
-      ``block_s``, ``n_blocks`` — resolved kernel blocking.
+      ``block_s``, ``n_blocks`` — resolved kernel blocking;
+      paged only: ``page_dmas``, ``grid_steps``, ``pages_per_block``.
     """
-    paged = block_tables is not None
     kh, hsz = k.shape[1], k.shape[3]
     b = q.shape[0]
-    if paged:
-        block_s = k.shape[2]                       # page size is the block
-        n_blocks = np.shape(block_tables)[1]       # logical pages
-        s_cap = n_blocks * block_s
-    else:
-        s_cap = k.shape[2]
-        block_s = min(block_s, round_up(s_cap, 128))
-        s_pad = round_up(s_cap, block_s)
-        n_blocks = s_pad // block_s
-
+    el = jnp.dtype(k.dtype).itemsize
     tl = np.broadcast_to(np.asarray(total_len, np.int32).reshape(-1), (b,))
+    if block_tables is not None:
+        return _paged_accounting(
+            np.asarray(block_tables, np.int32), tl, rank, window, groups,
+            kvp=kvp, rr_block=rr_block, block_s=block_s, prune=prune,
+            page_s=k.shape[2], kh=kh, hsz=hsz, el=el,
+            quant=kscale is not None)
+    s_cap = k.shape[2]
+    block_s = min(block_s, round_up(s_cap, 128))
+    s_pad = round_up(s_cap, block_s)
+    n_blocks = s_pad // block_s
     if prune:
         lo, nb = prune_block_range(
             jnp.asarray(tl), jnp.asarray(rank, jnp.int32),
             jnp.asarray(slot_offset, jnp.int32), jnp.asarray(window, jnp.int32),
             kvp=kvp, rr_block=rr_block, block_s=block_s, s_true=s_cap,
             contiguous=contiguous)
-        lo, nb = np.asarray(lo), np.asarray(nb)
-        if groups is not None:
-            # suffix pass: the span's lower bound is lifted to the first
-            # unshared page (mirrors decode_index_maps grouped clamp)
-            start = np.broadcast_to(
-                np.asarray(groups[1], np.int32).reshape(-1), (b,))
-            lo2 = np.maximum(lo, start)
-            nb = np.maximum(lo + nb - lo2, 0)
         # a fully-pruned request still references one (clamped) block: the
         # grid's first step fetches it before pl.when skips the compute
-        per_req = np.maximum(nb, 1)
+        per_req = np.maximum(np.asarray(nb), 1)
     else:
         per_req = np.full((b,), n_blocks)
-    prefix_blocks = 0
-    if groups is not None:
-        # prefix pass grid is (B group rows, Kh, n_blocks): row g streams
-        # its max(gnp, 1) span-clamped shared pages once per *group*
-        gid = np.broadcast_to(np.asarray(groups[0], np.int32).reshape(-1),
-                              (b,))
-        gnp_req = np.broadcast_to(np.asarray(groups[1], np.int32).reshape(-1),
-                                  (b,))
-        gnp = np.zeros((b,), np.int32)
-        np.maximum.at(gnp, gid, gnp_req)
-        prefix_blocks = int(kh * np.maximum(gnp, 1).sum())
-    suffix_blocks = int(kh * per_req.sum())
-    blocks_visited = prefix_blocks + suffix_blocks
+    blocks_visited = int(kh * per_req.sum())
     blocks_total = b * kh * n_blocks
-    el = jnp.dtype(k.dtype).itemsize
     blk_bytes = 2 * block_s * hsz * el                    # K + V payload
     if kscale is not None:
         blk_bytes += 2 * block_s * 4                      # f32 dequant scales
@@ -308,12 +313,77 @@ def flash_decode_accounting(q, k, v, total_len, rank, *, kvp: int = 1,
         "blocks_total": blocks_total,
         "bytes_read": blocks_visited * blk_bytes,
         "bytes_total": blocks_total * blk_bytes,
-        "prefix_blocks": prefix_blocks,
-        "suffix_blocks": suffix_blocks,
-        "prefix_bytes": prefix_blocks * blk_bytes,
-        "suffix_bytes": suffix_blocks * blk_bytes,
+        "prefix_blocks": 0,
+        "suffix_blocks": blocks_visited,
+        "prefix_bytes": 0,
+        "suffix_bytes": blocks_visited * blk_bytes,
         "block_s": block_s,
         "n_blocks": n_blocks,
+    }
+
+
+def _page_dmas(tables, pg_lo, pg_hi, *, pages: int, max_pages: int) -> int:
+    """Page copies the grid pipeline issues for rows fetching pages
+    ``[pg_lo, pg_hi)`` of ``tables``: each page slot fetches at the first
+    grid step and whenever its ``page_schedule`` page changes between
+    consecutive (row-major) steps."""
+    sched = np.asarray(page_schedule(tables, pg_lo, pg_hi, pages=pages,
+                                     max_pages=max_pages))
+    seq = sched.reshape(sched.shape[0], -1, pages).transpose(2, 0, 1)
+    seq = seq.reshape(pages, -1)
+    return int(pages + np.count_nonzero(seq[:, 1:] != seq[:, :-1]))
+
+
+def _paged_accounting(tables, tl, rank, window, groups, *, kvp, rr_block,
+                      block_s, prune, page_s, kh, hsz, el, quant):
+    """``flash_decode_accounting`` of a paged call (see there)."""
+    b, max_pages = tables.shape
+    pages = block_pages(block_s, page_s, max_pages)
+    n_sb = -(-max_pages // pages)
+    start = prefix_dmas = prefix_blocks = 0
+    if groups is not None:
+        gid = np.broadcast_to(np.asarray(groups[0], np.int32).reshape(-1),
+                              (b,))
+        start = np.broadcast_to(
+            np.asarray(groups[1], np.int32).reshape(-1), (b,)) // pages
+        # prefix pass: grid (B group rows, n_sb); row g streams the pages
+        # of its gnb whole shared S-blocks once per *group*
+        gnb = np.zeros((b,), np.int32)
+        np.maximum.at(gnb, gid, start)
+        gtab = np.zeros_like(tables)
+        gtab[gid] = tables
+        prefix_blocks = int(gnb.sum())
+        prefix_dmas = _page_dmas(gtab, np.zeros((b,), np.int32),
+                                 np.minimum(gnb * pages, max_pages),
+                                 pages=pages, max_pages=max_pages)
+        start = jnp.asarray(start)
+    pg_lo, pg_hi, blk_lo, blk_hi = paged_spans(
+        jnp.asarray(tl), jnp.asarray(rank, jnp.int32),
+        jnp.asarray(window, jnp.int32),
+        start if groups is not None else None, kvp=kvp, rr_block=rr_block,
+        page_rows=page_s, pages=pages, max_pages=max_pages, prune=prune)
+    pg_lo, pg_hi = np.broadcast_to(pg_lo, (b,)), np.broadcast_to(pg_hi, (b,))
+    suffix_blocks = int(np.sum(np.asarray(blk_hi) - np.asarray(blk_lo)))
+    suffix_dmas = _page_dmas(tables, pg_lo, pg_hi, pages=pages,
+                             max_pages=max_pages)
+    page_bytes = 2 * kh * page_s * hsz * el               # K + V, all heads
+    if quant:
+        page_bytes += 2 * kh * page_s * 4                 # f32 dequant scales
+    page_dmas = prefix_dmas + suffix_dmas
+    return {
+        "blocks_visited": prefix_blocks + suffix_blocks,
+        "blocks_total": b * n_sb,
+        "bytes_read": page_dmas * page_bytes,
+        "bytes_total": b * max_pages * page_bytes,
+        "prefix_blocks": prefix_blocks,
+        "suffix_blocks": suffix_blocks,
+        "prefix_bytes": prefix_dmas * page_bytes,
+        "suffix_bytes": suffix_dmas * page_bytes,
+        "block_s": pages * page_s,
+        "n_blocks": n_sb,
+        "page_dmas": page_dmas,
+        "grid_steps": (2 if groups is not None else 1) * b * n_sb,
+        "pages_per_block": pages,
     }
 
 # --- static-analysis contract -------------------------------------------
@@ -321,7 +391,8 @@ def flash_decode_accounting(q, k, v, total_len, rank, *, kvp: int = 1,
 # default audit lattice: prune x window x paged x kv8 x rr/contiguous x
 # slot_offset x fused append, at interpreter-friendly toy shapes.  Mode
 # exclusions mirror flash_decode's assertions (append/paged exclude the
-# contiguous layout and the cache-slice fast path).
+# contiguous layout and the cache-slice fast path).  Paged cases gather
+# ``pages`` pages per S-block (3 does not divide the 4-page table).
 _CONTRACT_LATTICE = (
     dict(case="rr-prune"),
     dict(case="rr-dense", prune=False),
@@ -337,6 +408,9 @@ _CONTRACT_LATTICE = (
     dict(case="append-window", append=True, window=6),
     dict(case="paged-prune", paged=True),
     dict(case="paged-dense", paged=True, prune=False),
+    dict(case="paged-window", paged=True, window=6),
+    dict(case="paged-one-page", paged=True, pages=1),
+    dict(case="paged-ragged", paged=True, pages=3, append=True),
     dict(case="paged-kv8", paged=True, quant=True),
     dict(case="paged-append-kv8", paged=True, quant=True, append=True),
     dict(case="paged-sink-tail", paged=True, sink_tail=True),
@@ -353,114 +427,79 @@ def decode_case_contract(case="rr-prune", *, b=2, qh=4, kh=2, hsz=8,
                          s_cap=16, kvp=2, rr_block=2, block_s=4, rank=1,
                          total_len=(5, 13), window=0, slot_offset=0,
                          contiguous=False, quant=False, append=False,
-                         prune=True, paged=False, sink_tail=False,
-                         grouped=False, shared_prefix=False, seed=0):
+                         prune=True, paged=False, pages=2, sink_tail=False,
+                         grouped=False, shared_prefix=False, table=None,
+                         seed=0):
     """Build the ``KernelContract`` for one flash_decode configuration.
 
     Mirrors ``flash_decode``'s geometry resolution (padding, block sizing,
     prefetch layout) at the given shapes and binds the *same* index_map
     callables the kernel would pass to ``pallas_call``
-    (``kernel.decode_index_maps``), so the static auditor proves properties
-    of the real DMA addressing.  ``sink_tail`` leaves unallocated paged
-    table entries on the reserved sink page 0.  ``grouped`` audits the
-    grouped-suffix maps: a ``start [B]`` prefetch operand joins the table,
-    the init-state operands precede q, and the pruned span is lifted to the
-    start page.  ``shared_prefix`` makes the requests share their leading
-    table page (request 1 maps request 0's first page) and sets the
-    ``shared_ok`` note so the table audit allows the read-only duplicate —
-    append targets must still be exclusive.  Returns one
+    (``kernel.decode_index_maps``, ``kernel.paged_index_maps``), so the
+    static auditor proves properties of the real DMA addressing.  Paged
+    contracts use ``block_s``-row pages, ``pages`` of them per S-block, one
+    streamed operand per page slot and plane.  ``sink_tail`` leaves
+    unallocated paged table entries on the reserved sink page 0.
+    ``grouped`` audits the grouped-suffix maps: a ``start [B]`` prefetch
+    operand (in S-blocks) joins the table, the init-state operands precede
+    q, and the live span starts at the start block.  ``shared_prefix`` makes
+    the requests share their leading table page (request 1 maps request
+    0's first page) and sets the ``shared_ok`` note so the table audit
+    allows the read-only duplicate — append targets must still be
+    exclusive.  ``table`` replaces the paged contract's shuffled block
+    table (the page schedule is worked out from it).  Returns one
     ``KernelContract``; ``flash_decode_contract`` assembles the lattice.
     """
+    if paged:
+        return _paged_case_contract(
+            case, b=b, qh=qh, kh=kh, hsz=hsz, s_cap=s_cap, kvp=kvp,
+            rr_block=rr_block, page_rows=block_s, pages=pages, rank=rank,
+            total_len=total_len, window=window, quant=quant, append=append,
+            prune=prune, sink_tail=sink_tail, grouped=grouped,
+            shared_prefix=shared_prefix, table=table, seed=seed)
     g = qh // kh
     qp = round_up(g, 8)
-    if paged:
-        n_blocks = s_cap // block_s
-        s_pad = n_blocks * block_s
-    else:
-        block_s = min(block_s, round_up(s_cap, 128))
-        s_pad = round_up(s_cap, block_s)
-        n_blocks = s_pad // block_s
+    block_s = min(block_s, round_up(s_cap, 128))
+    s_pad = round_up(s_cap, block_s)
+    n_blocks = s_pad // block_s
     s_true = s_cap
 
     meta = np.array([rank, slot_offset, window], np.int32)
     tl = np.broadcast_to(np.asarray(total_len, np.int32).reshape(-1), (b,))
-    prefetch = (meta, tl)
-
-    table = None
-    n_pool = None
-    start = None
-    if paged:
-        rng = np.random.RandomState(seed)
-        n_pool = 1 + b * n_blocks            # page 0 is the reserved sink
-        table = (1 + rng.permutation(b * n_blocks)
-                 .reshape(b, n_blocks)).astype(np.int32)
-        if sink_tail:
-            # entries past the valid span are unallocated -> sink page 0
-            need = (tl + block_s - 1) // block_s
-            for i in range(b):
-                table[i, max(int(need[i]), 1):] = 0
-        if shared_prefix:
-            # both requests map request 0's first page as their shared
-            # (read-only, refcounted) leading prefix page
-            table[1, 0] = table[0, 0]
-        prefetch = prefetch + (table,)
-    if grouped:
-        assert paged, "grouped suffix maps require paged mode"
-        # first unshared logical page per request: with shared_prefix both
-        # requests resume past the one shared page; otherwise request 0 is
-        # a singleton (start 0) and request 1 pretends one prefix page
-        start = (np.full((b,), 1, np.int32) if shared_prefix
-                 else np.arange(b, dtype=np.int32) % 2)
-        prefetch = prefetch + (start,)
 
     idx = decode_index_maps(
         kvp=kvp, rr_block=rr_block, block_s=block_s, s_true=s_true,
-        n_blocks=n_blocks, contiguous=contiguous, prune=prune, paged=paged,
-        grouped=grouped)
+        n_blocks=n_blocks, contiguous=contiguous, prune=prune)
 
-    kv_shape = ((n_pool, kh, block_s, hsz) if paged
-                else (b, kh, s_pad, hsz))
-    sc_shape = ((n_pool, kh, block_s) if paged else (b, kh, s_pad))
-    pax = 0 if paged else None
+    kv_shape = (b, kh, s_pad, hsz)
+    sc_shape = (b, kh, s_pad)
     rw = append_rows(block_s)
 
-    operands = []
-    if grouped:
-        # the prefix pass's raw state precedes q (kernel arg order)
-        operands += [
-            Operand("acc0", (b, kh, qp, hsz), (1, 1, qp, hsz), idx["q"]),
-            Operand("m0", (b, kh, qp, 1), (1, 1, qp, 1), idx["lse"]),
-            Operand("l0", (b, kh, qp, 1), (1, 1, qp, 1), idx["lse"]),
-        ]
-    operands += [
+    operands = [
         Operand("q", (b, kh, qp, hsz), (1, 1, qp, hsz), idx["q"]),
         Operand("k", kv_shape, (1, 1, block_s, hsz), idx["kv"],
-                streamed=True, paged_axis=pax),
+                streamed=True),
         Operand("v", kv_shape, (1, 1, block_s, hsz), idx["kv"],
-                streamed=True, paged_axis=pax),
+                streamed=True),
     ]
     if quant:
         operands += [
             Operand("kscale", sc_shape, (1, 1, block_s), idx["scale"],
-                    streamed=True, paged_axis=pax),
+                    streamed=True),
             Operand("vscale", sc_shape, (1, 1, block_s), idx["scale"],
-                    streamed=True, paged_axis=pax),
+                    streamed=True),
         ]
     if append:
         operands += [
             Operand("k_new", (b, kh, 1, hsz), (1, 1, 1, hsz), idx["new"]),
             Operand("v_new", (b, kh, 1, hsz), (1, 1, 1, hsz), idx["new"]),
-            Operand("k_row_in", kv_shape, (1, 1, rw, hsz), idx["row"],
-                    paged_axis=pax),
-            Operand("v_row_in", kv_shape, (1, 1, rw, hsz), idx["row"],
-                    paged_axis=pax),
+            Operand("k_row_in", kv_shape, (1, 1, rw, hsz), idx["row"]),
+            Operand("v_row_in", kv_shape, (1, 1, rw, hsz), idx["row"]),
         ]
         if quant:
             operands += [
-                Operand("kscale_row_in", sc_shape, (1, 1, rw), idx["srow"],
-                        paged_axis=pax),
-                Operand("vscale_row_in", sc_shape, (1, 1, rw), idx["srow"],
-                        paged_axis=pax),
+                Operand("kscale_row_in", sc_shape, (1, 1, rw), idx["srow"]),
+                Operand("vscale_row_in", sc_shape, (1, 1, rw), idx["srow"]),
             ]
     operands += [
         Operand("out", (b, kh, qp, hsz), (1, 1, qp, hsz), idx["q"],
@@ -468,133 +507,260 @@ def decode_case_contract(case="rr-prune", *, b=2, qh=4, kh=2, hsz=8,
         Operand("lse", (b, kh, qp, 1), (1, 1, qp, 1), idx["lse"],
                 kind="out"),
     ]
-    npre = (3 if paged else 2) + (1 if grouped else 0)
-    qoff = npre + (3 if grouped else 0)
     aliases = {}
     if append:
         operands += [
             Operand("k_row_out", kv_shape, (1, 1, rw, hsz), idx["row"],
-                    kind="out", alias_of="k", paged_axis=pax),
+                    kind="out", alias_of="k"),
             Operand("v_row_out", kv_shape, (1, 1, rw, hsz), idx["row"],
-                    kind="out", alias_of="v", paged_axis=pax),
+                    kind="out", alias_of="v"),
         ]
-        aliases = {qoff + 1: 2, qoff + 2: 3}
+        aliases = {3: 2, 4: 3}
         if quant:
             operands += [
                 Operand("kscale_row_out", sc_shape, (1, 1, rw), idx["srow"],
-                        kind="out", alias_of="kscale", paged_axis=pax),
+                        kind="out", alias_of="kscale"),
                 Operand("vscale_row_out", sc_shape, (1, 1, rw), idx["srow"],
-                        kind="out", alias_of="vscale", paged_axis=pax),
+                        kind="out", alias_of="vscale"),
             ]
-            aliases = {qoff + 1: 2, qoff + 2: 3, qoff + 3: 4, qoff + 4: 5}
+            aliases = {3: 2, 4: 3, 5: 4, 6: 5}
 
     active = None
     if prune:
-        lo_d, nb_d = prune_block_range(
+        _, nb_d = prune_block_range(
             jnp.asarray(tl), jnp.asarray(rank, jnp.int32),
             jnp.asarray(slot_offset, jnp.int32),
             jnp.asarray(window, jnp.int32), kvp=kvp, rr_block=rr_block,
             block_s=block_s, s_true=s_true, contiguous=contiguous)
-        lo_np, nb_np = np.asarray(lo_d), np.asarray(nb_d)
-        if grouped:
-            lo2 = np.maximum(lo_np, start)
-            nb_np = np.maximum(lo_np + nb_np - lo2, 0)
+        nb_np = np.asarray(nb_d)
 
         def active(bi, h, s, _nb=nb_np):
             return bool(s < _nb[bi])
-    # dense grouped mode skips compute below start but still streams every
-    # block (no index clamp), so no elision predicate applies there
 
     expected_row = None
     if append:
         j_new = np.asarray(_append_slot(jnp.asarray(tl), kvp, rr_block,
                                         s_pad))
 
-        def expected_row(bi, h, _j=j_new, _tbl=table):
+        def expected_row(bi, h, _j=j_new):
             # the (1, 1, rw, hsz) window block holding the appended row
-            j = int(_j[bi])
-            if _tbl is not None:
-                return (int(_tbl[bi, j // block_s]), h,
-                        (j % block_s) // rw, 0)
-            return (bi, h, j // rw, 0)
+            return (bi, h, int(_j[bi]) // rw, 0)
 
     return KernelContract(
         family="flash_decode", case=case, grid=(b, kh, n_blocks),
-        operands=operands, prefetch=prefetch, stream_axis=2,
+        operands=operands, prefetch=(meta, tl), stream_axis=2,
+        aliases=aliases, active=active, expected_row=expected_row,
+        notes=dict(kvp=kvp, rr_block=rr_block, block_s=block_s,
+                   s_true=s_true, prune=prune, paged=False, quant=quant,
+                   append=append, contiguous=contiguous, window=window,
+                   slot_offset=slot_offset))
+
+
+def _page_operands(idx, pages, n_pool, kh, page_rows, hsz, quant):
+    """One streamed operand per page slot and pool plane (the kernel passes
+    each plane once per slot); ``plane`` names the array they all read."""
+    ops = [Operand(f"{plane}{p}", (n_pool, kh, page_rows, hsz),
+                   (1, kh, page_rows, hsz), idx["pages"][p], streamed=True,
+                   paged_axis=0, plane=plane)
+           for plane in ("k", "v") for p in range(pages)]
+    if quant:
+        ops += [Operand(f"{plane}{p}", (n_pool, kh, page_rows),
+                        (1, kh, page_rows), idx["scales"][p], streamed=True,
+                        paged_axis=0, plane=plane)
+                for plane in ("kscale", "vscale") for p in range(pages)]
+    return ops
+
+
+def _paged_case_contract(case, *, b, qh, kh, hsz, s_cap, kvp, rr_block,
+                         page_rows, pages, rank, total_len, window, quant,
+                         append, prune, sink_tail, grouped, shared_prefix,
+                         table, seed):
+    """``decode_case_contract`` of a paged configuration: grid ``(B,
+    S-blocks)``, bound to ``kernel.paged_index_maps``."""
+    qp = round_up(qh // kh, 8)
+    max_pages = s_cap // page_rows
+    pages = block_pages(pages * page_rows, page_rows, max_pages)
+    n_sb = -(-max_pages // pages)
+    meta = np.array([rank, 0, window], np.int32)
+    tl = np.broadcast_to(np.asarray(total_len, np.int32).reshape(-1), (b,))
+
+    rng = np.random.RandomState(seed)
+    n_pool = 1 + b * max_pages               # page 0 is the reserved sink
+    if table is None:
+        table = (1 + rng.permutation(b * max_pages)
+                 .reshape(b, max_pages)).astype(np.int32)
+    if sink_tail:
+        # entries past the valid span are unallocated -> sink page 0
+        need = (tl + page_rows - 1) // page_rows
+        for i in range(b):
+            table[i, max(int(need[i]), 1):] = 0
+    if shared_prefix:
+        # both requests map request 0's first page as their shared
+        # (read-only, refcounted) leading prefix page
+        table[1, 0] = table[0, 0]
+    start = None
+    if grouped:
+        # first unshared S-block per request: with shared_prefix both
+        # requests resume past the shared block; otherwise request 0 is a
+        # singleton (start 0) and request 1 pretends one prefix block
+        start = (np.full((b,), 1, np.int32) if shared_prefix
+                 else np.arange(b, dtype=np.int32) % 2)
+    pg_lo, pg_hi, blk_lo, blk_hi = paged_spans(
+        jnp.asarray(tl), jnp.asarray(rank, jnp.int32),
+        jnp.asarray(window, jnp.int32),
+        None if start is None else jnp.asarray(start), kvp=kvp,
+        rr_block=rr_block, page_rows=page_rows, pages=pages,
+        max_pages=max_pages, prune=prune)
+    j_new = np.asarray(_append_slot(jnp.asarray(tl), kvp, rr_block,
+                                    max_pages * page_rows))
+    prefetch = (meta, tl,
+                np.asarray(page_schedule(table, pg_lo, pg_hi, pages=pages,
+                                         max_pages=max_pages)),
+                table[np.arange(b), j_new // page_rows])
+    if grouped:
+        prefetch = prefetch + (start,)
+
+    idx = paged_index_maps(kvp=kvp, rr_block=rr_block, page_rows=page_rows,
+                           pages=pages, max_pages=max_pages)
+    res = idx["res"]
+    kv_shape = (n_pool, kh, page_rows, hsz)
+    sc_shape = (n_pool, kh, page_rows)
+    rw = append_rows(page_rows)
+
+    operands = []
+    if grouped:
+        # the prefix pass's raw state precedes q (kernel arg order)
+        operands += [
+            Operand("acc0", (b, kh, qp, hsz), (1, kh, qp, hsz), res),
+            Operand("m0", (b, kh, qp, 1), (1, kh, qp, 1), res),
+            Operand("l0", (b, kh, qp, 1), (1, kh, qp, 1), res),
+        ]
+    operands += [Operand("q", (b, kh, qp, hsz), (1, kh, qp, hsz), res)]
+    operands += _page_operands(idx, pages, n_pool, kh, page_rows, hsz, quant)
+    if append:
+        operands += [
+            Operand("k_new", (b, kh, 1, hsz), (1, kh, 1, hsz), res),
+            Operand("v_new", (b, kh, 1, hsz), (1, kh, 1, hsz), res),
+            Operand("k_row_in", kv_shape, (1, kh, rw, hsz), idx["row"],
+                    paged_axis=0),
+            Operand("v_row_in", kv_shape, (1, kh, rw, hsz), idx["row"],
+                    paged_axis=0),
+        ]
+        if quant:
+            operands += [
+                Operand("kscale_row_in", sc_shape, (1, kh, rw), idx["srow"],
+                        paged_axis=0),
+                Operand("vscale_row_in", sc_shape, (1, kh, rw), idx["srow"],
+                        paged_axis=0),
+            ]
+    operands += [
+        Operand("out", (b, kh, qp, hsz), (1, kh, qp, hsz), res, kind="out"),
+        Operand("lse", (b, kh, qp, 1), (1, kh, qp, 1), res, kind="out"),
+    ]
+    npre = len(prefetch)
+    kv_pos = npre + (4 if grouped else 1)
+    aliases = {}
+    if append:
+        operands += [
+            Operand("k_row_out", kv_shape, (1, kh, rw, hsz), idx["row"],
+                    kind="out", alias_of="k0", paged_axis=0),
+            Operand("v_row_out", kv_shape, (1, kh, rw, hsz), idx["row"],
+                    kind="out", alias_of="v0", paged_axis=0),
+        ]
+        aliases = {kv_pos: 2, kv_pos + pages: 3}
+        if quant:
+            operands += [
+                Operand("kscale_row_out", sc_shape, (1, kh, rw), idx["srow"],
+                        kind="out", alias_of="kscale0", paged_axis=0),
+                Operand("vscale_row_out", sc_shape, (1, kh, rw), idx["srow"],
+                        kind="out", alias_of="vscale0", paged_axis=0),
+            ]
+            aliases.update({kv_pos + 2 * pages: 4, kv_pos + 3 * pages: 5})
+
+    active = None
+    if prune:
+        lo_np, hi_np = np.asarray(blk_lo), np.asarray(blk_hi)
+
+        def active(bi, s, _lo=lo_np, _hi=hi_np):
+            return bool(_lo[bi] <= s < _hi[bi])
+    # dense mode fetches every page (no elision predicate applies there)
+
+    expected_row = None
+    if append:
+        def expected_row(bi, h, _j=j_new, _tbl=table):
+            # the (1, Kh, rw, hsz) window block holding the appended row
+            j = int(_j[bi])
+            return (int(_tbl[bi, j // page_rows]), 0,
+                    (j % page_rows) // rw, 0)
+
+    return KernelContract(
+        family="flash_decode", case=case, grid=(b, n_sb),
+        operands=operands, prefetch=prefetch, stream_axis=1,
         aliases=aliases, active=active, expected_row=expected_row,
         table=table, n_pool=n_pool,
-        notes=dict(kvp=kvp, rr_block=rr_block, block_s=block_s,
-                   s_true=s_true, prune=prune, paged=paged, quant=quant,
-                   append=append, contiguous=contiguous, window=window,
-                   slot_offset=slot_offset, grouped=grouped,
+        notes=dict(kvp=kvp, rr_block=rr_block, page_rows=page_rows,
+                   pages=pages, prune=prune, paged=True, quant=quant,
+                   append=append, window=window, grouped=grouped,
                    shared_ok=shared_prefix))
 
 
 def prefix_case_contract(case="grouped-prefix", *, g=2, gm=2, kh=2, hsz=8,
-                         qp=8, kvp=1, rr_block=2, block_s=4, n_blocks=4,
-                         window=0, quant=False, seed=0):
+                         qp=8, kvp=1, rr_block=2, block_s=4, pages=2,
+                         max_pages=5, window=0, quant=False, seed=0):
     """``KernelContract`` for the grouped shared-prefix pass.
 
-    Grid ``(G, Kh, n_blocks)`` over group rows; binds the *same*
-    ``grouped_prefix_index_maps`` callables ``prefix_pass_kernel`` hands to
-    ``pallas_call``.  Group 0 holds two members sharing a two-page prefix,
-    group 1 is a memberless padding row (``gnp == 0``, all lengths 0) — the
-    degenerate shape every batch position the engine leaves ungrouped
-    takes, whose span clamp pins the stream to one page.
+    Grid ``(G, S-blocks)`` over group rows of ``pages``-page S-blocks;
+    binds the *same* ``prefix_index_maps`` callables
+    ``prefix_pass_kernel`` hands to ``pallas_call``.  Group 0 holds two
+    members sharing one S-block of prefix pages, group 1 is a memberless
+    padding row (``gnb == 0``, all lengths 0) — the degenerate shape every
+    batch position the engine leaves ungrouped takes, which holds the pages
+    its slots already have (no DMA) and computes nothing.
     """
     rows = gm * qp
     rng = np.random.RandomState(seed)
-    n_pool = 1 + g * n_blocks
-    gtab = (1 + rng.permutation(g * n_blocks)
-            .reshape(g, n_blocks)).astype(np.int32)
-    gnp = np.array([2] + [0] * (g - 1), np.int32)
+    n_pool = 1 + g * max_pages
+    gtab = (1 + rng.permutation(g * max_pages)
+            .reshape(g, max_pages)).astype(np.int32)
+    gnb = np.array([1] + [0] * (g - 1), np.int32)
     gtl = np.zeros((g, gm), np.int32)
-    gtl[0] = [2 * block_s + 1, 3 * block_s + 1][:gm]
+    gtl[0] = [pages * block_s + 1, (pages + 1) * block_s + 1][:gm]
     meta = np.array([0, 0, window], np.int32)
 
-    idx = grouped_prefix_index_maps(n_blocks=n_blocks)
-    operands = [
-        Operand("q", (g, kh, rows, hsz), (1, 1, rows, hsz), idx["q"]),
-        Operand("k", (n_pool, kh, block_s, hsz), (1, 1, block_s, hsz),
-                idx["kv"], streamed=True, paged_axis=0),
-        Operand("v", (n_pool, kh, block_s, hsz), (1, 1, block_s, hsz),
-                idx["kv"], streamed=True, paged_axis=0),
-    ]
-    if quant:
-        operands += [
-            Operand("kscale", (n_pool, kh, block_s), (1, 1, block_s),
-                    idx["scale"], streamed=True, paged_axis=0),
-            Operand("vscale", (n_pool, kh, block_s), (1, 1, block_s),
-                    idx["scale"], streamed=True, paged_axis=0),
-        ]
+    idx = prefix_index_maps(pages=pages)
+    res = idx["res"]
+    gsched = np.asarray(page_schedule(gtab, 0, np.minimum(gnb * pages,
+                                                          max_pages),
+                                      pages=pages, max_pages=max_pages))
+    operands = [Operand("q", (g, kh, rows, hsz), (1, kh, rows, hsz), res)]
+    operands += _page_operands(idx, pages, n_pool, kh, block_s, hsz, quant)
     operands += [
-        Operand("acc", (g, kh, rows, hsz), (1, 1, rows, hsz), idx["acc"],
+        Operand("acc", (g, kh, rows, hsz), (1, kh, rows, hsz), res,
                 kind="out"),
-        Operand("m", (g, kh, rows, 1), (1, 1, rows, 1), idx["ml"],
-                kind="out"),
-        Operand("l", (g, kh, rows, 1), (1, 1, rows, 1), idx["ml"],
-                kind="out"),
+        Operand("m", (g, kh, rows, 1), (1, kh, rows, 1), res, kind="out"),
+        Operand("l", (g, kh, rows, 1), (1, kh, rows, 1), res, kind="out"),
     ]
 
-    def active(gi, h, s, _np=gnp):
-        return bool(s < _np[gi])
+    def active(gi, s, _nb=gnb):
+        return bool(s < _nb[gi])
 
     return KernelContract(
-        family="flash_decode", case=case, grid=(g, kh, n_blocks),
-        operands=operands, prefetch=(meta, gnp, gtl, gtab), stream_axis=2,
+        family="flash_decode", case=case, grid=(g, -(-max_pages // pages)),
+        operands=operands, prefetch=(meta, gnb, gtl, gsched), stream_axis=1,
         active=active, table=gtab, n_pool=n_pool,
         notes=dict(kvp=kvp, rr_block=rr_block, block_s=block_s,
-                   quant=quant, grouped_prefix=True))
+                   pages=pages, quant=quant, grouped_prefix=True))
 
 
 def flash_decode_contract():
     """Contracts for the flash_decode audit lattice (``repro.analysis``).
 
     One ``KernelContract`` per configuration in the default lattice —
-    prune x window x paged x kv8 x rr/contiguous x slot_offset x fused
-    append x grouped/shared-prefix — each binding the kernel's real
-    index_map callables at toy shapes the auditor can enumerate
-    exhaustively, plus the grouped shared-prefix pass's own contracts.
+    prune x window x paged (pages per S-block) x kv8 x rr/contiguous x
+    slot_offset x fused append x grouped/shared-prefix — each binding the
+    kernel's real index_map callables at toy shapes the auditor can
+    enumerate exhaustively, plus the grouped shared-prefix pass's own
+    contracts.
     """
     suite = [decode_case_contract(**dict(c)) for c in _CONTRACT_LATTICE]
     suite.append(prefix_case_contract())

@@ -59,7 +59,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.utils import NEG_INF
 from repro.kernels.pruning import phys_block as _phys_block
-from repro.kernels.pruning import table_block as _table_block  # noqa: F401
 
 
 def prefill_block_range(qi, kv_len, q_offset, window, *, causal: bool,

@@ -31,8 +31,8 @@ def span_clamp(step, lo, nb, n_blocks: int):
     """Clamp grid step ``step`` into the valid span ``[lo, lo + nb)`` and
     the array bounds ``[0, n_blocks)``.
 
-    The one in-bounds clamp shared by ``phys_block``/``table_block`` (and
-    through them every pruned kernel index_map) and replayed by the static
+    The one in-bounds clamp shared through ``phys_block`` by every pruned
+    fixed-layout kernel index_map, and replayed by the static
     auditor: ``lo + step`` while inside the span, then pinned to the span's
     last block — the same block as the previous step, so Pallas elides the
     HBM->VMEM copy.  Total (never out of ``[0, n_blocks)``) even for empty
@@ -53,14 +53,3 @@ def phys_block(step, lo, nb, n_blocks: int):
     blocks directly."""
     return span_clamp(step, lo, nb, n_blocks)
 
-
-def table_block(step, lo, nb, n_blocks: int, table_row):
-    """Paged generalization of ``phys_block``: the *logical* page id walks
-    the clamped span exactly as in the fixed layout, then the scalar-
-    prefetched block-table row maps it to the physical pool page.  Pruned
-    grid steps re-reference the previous step's logical page, hence the
-    same table entry, hence the same physical page — so the DMA-elision
-    property survives the indirection unchanged.  ``table_row`` is one
-    request's ``[max_pages]`` table (a Pallas scalar-prefetch ref slice or
-    an array)."""
-    return table_row[span_clamp(step, lo, nb, n_blocks)]

@@ -424,7 +424,7 @@ def build_serve_step(cfg: ArchConfig, mesh: Mesh, hx: HelixConfig, *,
         state carries pool planes ``[L, n_blocks, Kh, block_s, hsz]`` plus a
         ``block_tables`` [B, max_pages] leaf instead of fixed per-slot rows
         (core/kvcache.py paged layout; bit-exact vs fixed at the same
-        ``attn_block_s`` partition).
+        ``attn_block_s`` partition, a multiple of the page rows).
     """
     hx = _resolve_overrides(hx, attn_backend=attn_backend,
                             fuse_append=fuse_append,

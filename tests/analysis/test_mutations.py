@@ -89,9 +89,36 @@ def test_oob_page_id_in_shuffled_table_is_bounds_page():
     c = decode_case_contract("paged-prune", paged=True)
     table = np.array(c.table, copy=True)
     table[1, 1] = c.n_pool + 3                 # points past the pool
-    mutated = dataclasses.replace(
-        c, table=table,
-        prefetch=c.prefetch[:2] + (table,))
+    mutated = decode_case_contract("paged-prune", paged=True, table=table)
+    found = _checks(audit_contract(mutated))
+    assert "bounds.page" in found
+
+
+def test_unclamped_page_slot_is_dma_elision():
+    """A paged page slot that walks the table without the live-span clamp
+    fetches a fresh page at every dead S-block -> dma.elision."""
+    c = decode_case_contract("paged-window", paged=True, window=6)
+    pages = c.notes["pages"]
+
+    table = jnp.asarray(c.table)
+
+    def unclamped(b, s, *_):
+        return (table[b, jnp.minimum(s * pages + 1, table.shape[1] - 1)],
+                0, 0, 0)
+
+    mutated = _replace_op(c, "k1", index_map=unclamped)
+    found = _checks(audit_contract(mutated))
+    assert "dma.elision" in found
+
+
+def test_page_slot_past_pool_is_bounds_page():
+    """A page slot addressing one page past the pool (an unchecked table
+    read) -> bounds.page on the pool axis."""
+    c = decode_case_contract("paged-prune", paged=True)
+    v0 = next(op for op in c.operands if op.name == "v0")
+    past = _wrap_map(v0.index_map,
+                     lambda t: (t[0] + c.n_pool,) + tuple(t[1:]))
+    mutated = _replace_op(c, "v0", index_map=past)
     found = _checks(audit_contract(mutated))
     assert "bounds.page" in found
 
@@ -102,8 +129,7 @@ def test_duplicate_page_across_requests_is_alias_race():
     c = decode_case_contract("paged-prune", paged=True)
     table = np.array(c.table, copy=True)
     table[1, 0] = table[0, 0]                  # request 1 steals req 0's page
-    mutated = dataclasses.replace(
-        c, table=table, prefetch=c.prefetch[:2] + (table,))
+    mutated = decode_case_contract("paged-prune", paged=True, table=table)
     found = _checks(audit_contract(mutated))
     assert "alias.race" in found
 

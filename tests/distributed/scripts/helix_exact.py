@@ -130,7 +130,10 @@ for b in range(B):
         pool_k = pool_k.at[phys].set(pk_pages[p])
         pool_v = pool_v.at[phys].set(pv_pages[p])
 tbl = jnp.asarray(tbl)
-for hxf, hxp_base in ((hx_bs, hx_bs), (hx_bs_pl, hx_bs_pl)):
+# pallas also at two pages an S-block (fixed at the same 2*RR slots)
+hx_bs2_pl = dataclasses.replace(hx_pl, attn_block_s=2 * RR)
+for hxf, hxp_base in ((hx_bs, hx_bs), (hx_bs_pl, hx_bs_pl),
+                      (hx_bs2_pl, hx_bs2_pl)):
     hxp = dataclasses.replace(hxp_base, paged_kv=True)
     for tl_case, win in ((total_len, 0), (tls, 0), (tls, 64)):
         with set_mesh(mesh):
@@ -140,7 +143,8 @@ for hxf, hxp_base in ((hx_bs, hx_bs), (hx_bs_pl, hx_bs_pl)):
                 mesh, hxp, q, k, v, tl_case, window=win,
                 block_tables=t))(q, pool_k, pool_v, tbl)
         np.testing.assert_array_equal(np.asarray(of), np.asarray(op))
-print("paged pool == fixed (KVP=8, ref + pallas, windowed, [B] tl): OK")
+print("paged pool == fixed (KVP=8, ref + pallas at 1 and 2 pages a "
+      "block, windowed, [B] tl): OK")
 
 # paged fused append == fixed fused append (pool planes reassemble exactly)
 kn_p = jnp.asarray(rng.standard_normal((B, KH, HSZ), np.float32))

@@ -181,11 +181,43 @@ def _sds(shape, dtype=jnp.float32):
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
-def test_grouped_accounting_prefix_bound_and_bruteforce():
+def _slot_dmas_oracle(tables, spans, pages):
+    """Brute force of the page-slot pipeline: slot ``p`` holds logical page
+    ``s * pages + p`` while it lies in the row's ``[lo, hi)`` span, else
+    the nearest of its own in-span pages (with none, its first page at or
+    past ``lo``, within the table); a row with an empty span keeps the
+    page the slot holds.  A DMA is issued at the first grid step and
+    whenever the held pool page changes."""
+    mp = tables.shape[1]
+    n_sb = -(-mp // pages)
+    dmas = 0
+    for p in range(pages):
+        held = None
+        for r, (lo, hi) in enumerate(spans):
+            if hi <= lo:
+                continue
+            own = [lg for lg in range(lo, hi) if lg % pages == p]
+            for s in range(n_sb):
+                lg = s * pages + p
+                if not own:
+                    lg = min(lo + (p - lo) % pages, mp - 1)
+                elif lg not in own:
+                    lg = min(own) if lg < min(own) else max(own)
+                page = int(tables[r, lg])
+                dmas += page != held
+                held = page
+        dmas += held is None          # no row fetches: the first step does
+    return dmas
+
+
+@pytest.mark.parametrize("pages", [1, 2], ids=["1page", "2pages"])
+def test_grouped_accounting_prefix_bound_and_bruteforce(pages):
     """Grouped shared-prefix decode: the accounting's two-pass split is
     pinned against brute-force enumeration, and the prefix read volume
     scales with the number of *groups*, not the number of requests — the
-    ~1/group_size bytes-read reduction the CoDec-style pass exists for."""
+    ~1/group_size bytes-read reduction the CoDec-style pass exists for.
+    S-blocks hold ``pages`` whole pages; a shared span rounds down to
+    whole blocks."""
     b, kh, hsz = 6, 2, 32
     bs, mp = 16, 5
     n_pool = 16
@@ -203,52 +235,86 @@ def test_grouped_accounting_prefix_bound_and_bruteforce():
     gid = np.array([0, 0, 0, 3, 3, 3], np.int32)
     gnp = np.full((b,), pp, np.int32)
     kv = _sds((n_pool, kh, bs, hsz))
-    acc = flash_decode_accounting(
-        _sds((b, 8, hsz)), kv, kv, tl, 0, kvp=1, rr_block=bs,
-        block_tables=tables, groups=(gid, gnp))
+    common = dict(kvp=1, rr_block=bs, block_s=pages * bs,
+                  block_tables=tables)
+    acc = flash_decode_accounting(_sds((b, 8, hsz)), kv, kv, tl, 0,
+                                  groups=(gid, gnp), **common)
+    n_groups = len({int(g) for g in gid})
 
-    # brute force, prefix pass: grid row g streams max(group_np_g, 1)
-    # pages (memberless rows fetch the clamped sink page once)
-    gnp_row = np.zeros((b,), np.int64)
-    np.maximum.at(gnp_row, gid, gnp)
-    prefix_oracle = kh * int(np.maximum(gnp_row, 1).sum())
-    # brute force, suffix pass: valid blocks at or past the shared span
+    # brute force, prefix pass: each group row computes its whole shared
+    # S-blocks; memberless rows compute none
+    shared_blocks = pp // pages
+    prefix_oracle = n_groups * shared_blocks
+    # brute force, suffix pass: blocks holding a valid slot at or past the
+    # shared blocks
     suffix_oracle = 0
     for r in range(b):
         pos = np.asarray(shard_positions(mp * bs, 0, 1, bs))
-        blocks = {j // bs for j in np.nonzero(pos < tl[r])[0]
-                  if j // bs >= gnp[r]}
-        suffix_oracle += kh * max(len(blocks), 1)
+        blocks = {j // (pages * bs) for j in np.nonzero(pos < tl[r])[0]}
+        suffix_oracle += len({k for k in blocks if k >= shared_blocks})
     assert acc["prefix_blocks"] == prefix_oracle
     assert acc["suffix_blocks"] == suffix_oracle
     assert acc["blocks_visited"] == prefix_oracle + suffix_oracle
+    assert acc["n_blocks"] == -(-mp // pages)
 
-    # the ISSUE bound: prefix reads scale with n_groups, not n_requests
-    n_groups = len({int(g) for g in gid})
-    assert acc["prefix_blocks"] <= kh * (pp * n_groups + (b - n_groups))
-    assert acc["prefix_blocks"] < kh * pp * b
-    # exact 1/group_size on the real (non-sink) prefix volume: 3 members
-    # per group read the shared pages once instead of three times
-    assert kh * n_groups * pp * 3 == kh * pp * b
+    # a group's shared pages are fetched once per group, not once per
+    # member, and memberless group rows fetch nothing
+    page_bytes = 2 * kh * bs * hsz * 4
+    shared_pages = n_groups * shared_blocks * pages
+    prefix_dmas = acc["prefix_bytes"] // page_bytes
+    assert prefix_dmas == shared_pages
+    assert shared_pages * 3 == shared_blocks * pages * b
+    gspans = [(0, shared_blocks * pages if r in gid else 0)
+              for r in range(b)]
+    gtab = np.zeros_like(tables)
+    gtab[gid] = tables
+    assert prefix_dmas == _slot_dmas_oracle(gtab, gspans, pages)
 
     # bytes split is consistent and the ungrouped call reports no prefix
-    blk_bytes = 2 * bs * hsz * 4
-    assert acc["prefix_bytes"] == acc["prefix_blocks"] * blk_bytes
-    assert acc["bytes_read"] == acc["blocks_visited"] * blk_bytes
-    un = flash_decode_accounting(
-        _sds((b, 8, hsz)), kv, kv, tl, 0, kvp=1, rr_block=bs,
-        block_tables=tables)
+    assert acc["bytes_read"] == acc["page_dmas"] * page_bytes
+    assert acc["bytes_read"] == acc["prefix_bytes"] + acc["suffix_bytes"]
+    un = flash_decode_accounting(_sds((b, 8, hsz)), kv, kv, tl, 0,
+                                 **common)
     assert un["prefix_blocks"] == un["prefix_bytes"] == 0
     assert un["suffix_blocks"] == un["blocks_visited"]
+    # ungrouped, every live page is one DMA, less those a slot already
+    # holds from the previous row (rows of a group share their prefix)
+    live = [(0, -(-int(t) // bs)) for t in tl]
+    assert un["page_dmas"] == _slot_dmas_oracle(tables, live, pages)
+    assert un["page_dmas"] <= sum(hi for _, hi in live)
+    sfx = [(shared_blocks * pages, hi) for _, hi in live]
+    assert acc["suffix_bytes"] // page_bytes == _slot_dmas_oracle(
+        tables, sfx, pages)
     # grouping strictly reduces total reads on this shared workload
     assert acc["bytes_read"] < un["bytes_read"]
 
-    # dense grouped: suffix degenerates to the full sweep, prefix unchanged
-    dense = flash_decode_accounting(
-        _sds((b, 8, hsz)), kv, kv, tl, 0, kvp=1, rr_block=bs,
-        block_tables=tables, groups=(gid, gnp), prune=False)
-    assert dense["suffix_blocks"] == b * kh * mp == dense["blocks_total"]
+    # dense grouped: the suffix walks every block above the shared ones,
+    # the prefix is unchanged
+    dense = flash_decode_accounting(_sds((b, 8, hsz)), kv, kv, tl, 0,
+                                    groups=(gid, gnp), prune=False, **common)
+    assert dense["suffix_blocks"] == b * (acc["n_blocks"] - shared_blocks)
     assert dense["prefix_blocks"] == prefix_oracle
+
+
+@pytest.mark.parametrize("pages", [1, 2], ids=["1page", "2pages"])
+def test_paged_idle_rows_fetch_nothing(pages):
+    """Idle batch rows (length 0) hold the pages their slots already have:
+    a batch with idle rows issues exactly the page DMAs of its live rows
+    run back to back."""
+    kh, hsz, bs, mp = 2, 32, 16, 5
+    tables = np.array([[0, 0, 0, 0, 0], [1, 2, 3, 0, 0], [0, 0, 0, 0, 0],
+                       [4, 5, 6, 7, 0], [0, 0, 0, 0, 0]], np.int32)
+    tl = np.array([0, 40, 0, 61, 0], np.int32)
+    kv = _sds((8, kh, bs, hsz))
+    common = dict(kvp=1, rr_block=bs, block_s=pages * bs)
+    acc = flash_decode_accounting(_sds((5, 8, hsz)), kv, kv, tl, 0,
+                                  block_tables=tables, **common)
+    live = flash_decode_accounting(_sds((2, 8, hsz)), kv, kv, tl[[1, 3]], 0,
+                                   block_tables=tables[[1, 3]], **common)
+    spans = [(0, -(-int(t) // bs)) for t in tl]
+    assert acc["page_dmas"] == live["page_dmas"]
+    assert acc["page_dmas"] == _slot_dmas_oracle(tables, spans, pages)
+    assert acc["blocks_visited"] == live["blocks_visited"]
 
 
 def test_registry_accounting_surface():

@@ -2,10 +2,13 @@
 bit-exactly, across the decode mode lattice.
 
 The paged pool is a page-granularity permutation of the fixed layout
-(core/kvcache.py): with the fixed kernel's S-block size pinned to the page
-size, both layouts stream identical tiles in identical order, so outputs
+(core/kvcache.py).  The paged kernel gathers ``pages`` whole pages per
+S-block; with the fixed kernel's S-block set to the same ``pages * PS``
+slots, both layouts run identical tiles in identical order, so outputs
 must be *bit*-identical — prune on/off, windowed, per-request lengths,
-int8, fused append, and through the ref (gather) backend too."""
+int8, fused append, sink entries, and through the ref (gather) backend
+too.  Pages per block cover one page, two, a block that does not divide
+the 4-page table, and the whole table."""
 import numpy as np
 import pytest
 import jax
@@ -21,6 +24,10 @@ PS = RR                     # per-rank page rows == rr_block
 MP = 4                      # logical pages per request
 S_LOC = MP * PS             # fixed local capacity
 B, QH, KH, HSZ = 3, 8, 2, 64
+
+# pages per S-block: one, two, one that leaves a ragged last block, all
+PAGES = pytest.mark.parametrize("pages", [1, 2, 3, MP],
+                                ids=["1page", "2pages", "3pages", "table"])
 
 
 def make_case(seed=0):
@@ -51,65 +58,74 @@ def quant(c):
     return payload, scale
 
 
-TLS = [jnp.asarray([200, 37, 150], jnp.int32), 150]
+# per-request lengths, one uniform length, and idle rows (length 0) around
+# a live one, whose page slots hold the live row's pages
+TLS = [jnp.asarray([200, 37, 150], jnp.int32), 150,
+       jnp.asarray([0, 37, 0], jnp.int32)]
 
 
+@PAGES
 @pytest.mark.parametrize("prune", [True, False])
 @pytest.mark.parametrize("window", [0, 48])
-@pytest.mark.parametrize("tl_i", [0, 1])
-def test_paged_equals_fixed(prune, window, tl_i):
+@pytest.mark.parametrize("tl_i", [0, 1, 2])
+def test_paged_equals_fixed(pages, prune, window, tl_i):
     q, k, v, pk, pv, tables = make_case()
     tl = TLS[tl_i]
+    bs = pages * PS
     of, lf = flash_decode(q, k, v, tl, 1, kvp=KVP, rr_block=RR,
-                          window=window, block_s=PS, prune=prune,
+                          window=window, block_s=bs, prune=prune,
                           interpret=True)
     op, lp = flash_decode(q, pk, pv, tl, 1, kvp=KVP, rr_block=RR,
-                          window=window, prune=prune, block_tables=tables,
-                          interpret=True)
+                          window=window, block_s=bs, prune=prune,
+                          block_tables=tables, interpret=True)
     np.testing.assert_array_equal(np.asarray(of), np.asarray(op))
     np.testing.assert_array_equal(np.asarray(lf), np.asarray(lp))
 
 
+@PAGES
 @pytest.mark.parametrize("prune", [True, False])
-def test_paged_quant_equals_fixed(prune):
+def test_paged_quant_equals_fixed(pages, prune):
     q, k, v, pk, pv, tables = make_case(1)
     k8, ks = quant(k); v8, vs = quant(v)
     pk8, pks = quant(pk); pv8, pvs = quant(pv)
     tl = TLS[0]
-    of, _ = flash_decode(q, k8, v8, tl, 1, kvp=KVP, rr_block=RR, block_s=PS,
+    bs = pages * PS
+    of, _ = flash_decode(q, k8, v8, tl, 1, kvp=KVP, rr_block=RR, block_s=bs,
                          kscale=ks, vscale=vs, prune=prune,
                          interpret=True)
     op, _ = flash_decode(q, pk8, pv8, tl, 1, kvp=KVP, rr_block=RR,
-                         kscale=pks, vscale=pvs, prune=prune,
+                         block_s=bs, kscale=pks, vscale=pvs, prune=prune,
                          block_tables=tables,
                          interpret=True)
     np.testing.assert_array_equal(np.asarray(of), np.asarray(op))
 
 
+@PAGES
 @pytest.mark.parametrize("quantized", [False, True])
-def test_paged_fused_append_equals_fixed(quantized):
+def test_paged_fused_append_equals_fixed(pages, quantized):
     q, k, v, pk, pv, tables = make_case(2)
     rng = np.random.default_rng(3)
     kn = jnp.asarray(rng.standard_normal((B, KH, HSZ), np.float32))
     vn = jnp.asarray(rng.standard_normal((B, KH, HSZ), np.float32))
     tl = jnp.asarray([201, 38, 151], jnp.int32)   # counts the appended token
+    bs = pages * PS
     if quantized:
         k8, ks = quant(k); v8, vs = quant(v)
         pk8, pks = quant(pk); pv8, pvs = quant(pv)
-        rf = flash_decode(q, k8, v8, tl, 1, kvp=KVP, rr_block=RR, block_s=PS,
+        rf = flash_decode(q, k8, v8, tl, 1, kvp=KVP, rr_block=RR, block_s=bs,
                           kscale=ks, vscale=vs, k_new=kn, v_new=vn,
                           interpret=True)
         rp = flash_decode(q, pk8, pv8, tl, 1, kvp=KVP, rr_block=RR,
-                          kscale=pks, vscale=pvs, k_new=kn, v_new=vn,
-                          block_tables=tables,
+                          block_s=bs, kscale=pks, vscale=pvs, k_new=kn,
+                          v_new=vn, block_tables=tables,
                           interpret=True)
     else:
-        rf = flash_decode(q, k, v, tl, 1, kvp=KVP, rr_block=RR, block_s=PS,
+        rf = flash_decode(q, k, v, tl, 1, kvp=KVP, rr_block=RR, block_s=bs,
                           k_new=kn, v_new=vn,
                           interpret=True)
         rp = flash_decode(q, pk, pv, tl, 1, kvp=KVP, rr_block=RR,
-                          k_new=kn, v_new=vn, block_tables=tables,
-                          interpret=True)
+                          block_s=bs, k_new=kn, v_new=vn,
+                          block_tables=tables, interpret=True)
     np.testing.assert_array_equal(np.asarray(rf[0]), np.asarray(rp[0]))
     # appended pool planes reassemble into the appended fixed caches
     for fixed, pool in zip(rf[2:], rp[2:]):
@@ -129,30 +145,42 @@ def test_ref_backend_gather_path():
     np.testing.assert_array_equal(np.asarray(lf), np.asarray(lp))
 
 
-def test_paged_accounting_matches_fixed_bound():
-    """Paged accounting replays the same logical ranges: identical visited
-    counts at the same block size, and the prune_smoke bound
-    (<= ceil(valid_len/block_s) + 1 per (b, h)) still holds."""
+@PAGES
+def test_paged_accounting_matches_fixed_bound(pages):
+    """Paged accounting replays the same logical ranges: a live S-block of
+    all heads per (b, h) block the fixed layout visits at the same block
+    size, and the prune_smoke bound (<= ceil(valid_len/block_s) + 1 blocks
+    per request) still holds; every live page is fetched exactly once."""
     from repro.kernels.flash_decode.ref import local_valid_len
     q, k, v, pk, pv, tables = make_case(5)
     tl = TLS[0]
+    bs = pages * PS
     fixed = flash_decode_accounting(q, k, v, tl, 1, kvp=KVP, rr_block=RR,
-                                    block_s=PS, prune=True)
+                                    block_s=bs, prune=True)
     paged = flash_decode_accounting(q, pk, pv, tl, 1, kvp=KVP, rr_block=RR,
-                                    prune=True, block_tables=tables)
-    assert paged["blocks_visited"] == fixed["blocks_visited"]
-    assert paged["block_s"] == PS and paged["n_blocks"] == MP
+                                    block_s=bs, prune=True,
+                                    block_tables=tables)
+    assert paged["blocks_visited"] * KH == fixed["blocks_visited"]
+    assert paged["block_s"] == bs and paged["pages_per_block"] == pages
+    assert paged["n_blocks"] == -(-MP // pages)
+    assert paged["grid_steps"] == B * paged["n_blocks"]
+    live_pages = 0
     for b in range(B):
         valid = int(local_valid_len(jnp.asarray(tl)[b], 1, KVP, RR))
-        bound = -(-valid // PS) + 1
-        per_bh = flash_decode_accounting(
+        live_pages += -(-valid // PS)
+        per_b = flash_decode_accounting(
             q[b:b + 1], pk, pv, jnp.asarray(tl)[b:b + 1], 1, kvp=KVP,
-            rr_block=RR, prune=True,
-            block_tables=tables[b:b + 1])["blocks_visited"] / KH
-        assert per_bh <= bound
+            rr_block=RR, block_s=bs, prune=True,
+            block_tables=tables[b:b + 1])
+        assert per_b["blocks_visited"] <= -(-valid // bs) + 1
+        # a page slot with no live page fetches its clamped page once
+        assert per_b["page_dmas"] == max(-(-valid // PS), pages)
+    assert paged["page_dmas"] >= live_pages
+    assert paged["bytes_read"] == paged["page_dmas"] * 2 * KH * PS * HSZ * 4
 
 
-def test_sink_entries_are_harmless():
+@PAGES
+def test_sink_entries_are_harmless(pages):
     """Table entries past a request's extent point at the sink page 0;
     the masked sweep over them must not change the output (dense prune=False
     sweep reads them, masks them)."""
@@ -160,10 +188,56 @@ def test_sink_entries_are_harmless():
     short = jnp.asarray([40, 40, 40], jnp.int32)   # < 1 page of positions
     trimmed = np.asarray(tables).copy()
     trimmed[:, 1:] = 0                             # only page 0 allocated
-    of, _ = flash_decode(q, k, v, short, 1, kvp=KVP, rr_block=RR,
-                         block_s=PS, prune=False,
-                         interpret=True)
-    op, _ = flash_decode(q, pk, pv, short, 1, kvp=KVP, rr_block=RR,
-                         prune=False, block_tables=jnp.asarray(trimmed),
-                         interpret=True)
-    np.testing.assert_array_equal(np.asarray(of), np.asarray(op))
+    bs = pages * PS
+    for prune in (False, True):
+        of, _ = flash_decode(q, k, v, short, 1, kvp=KVP, rr_block=RR,
+                             block_s=bs, prune=prune,
+                             interpret=True)
+        op, _ = flash_decode(q, pk, pv, short, 1, kvp=KVP, rr_block=RR,
+                             block_s=bs, prune=prune,
+                             block_tables=jnp.asarray(trimmed),
+                             interpret=True)
+        np.testing.assert_array_equal(np.asarray(of), np.asarray(op))
+
+
+# grouped decode: the prefix pass multiplies a group's stacked query rows in
+# one matmul, and XLA:CPU picks its dot kernel by row count, so outputs
+# agree with the ungrouped sweep to f32 rounding; appended planes bit for bit
+GROUPED_TOL = dict(rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("pages,shared", [(1, 3), (2, 3), (3, 2), (2, 1)],
+                         ids=["1page-3shared", "2pages-3shared",
+                              "3pages-2shared", "2pages-1shared"])
+@pytest.mark.parametrize("mode", ["plain", "window", "append"])
+def test_grouped_equals_ungrouped(pages, shared, mode):
+    """Rows 0 and 1 share their first ``shared`` pool pages, a span that is
+    not a whole number of S-blocks: grouping rounds it down to whole
+    blocks (none at all when it is shorter than one) and must not change
+    the result."""
+    q, _, _, pk, pv, tables = make_case(7)
+    tbl = np.asarray(tables).copy()
+    tbl[1, :shared] = tbl[0, :shared]
+    tbl = jnp.asarray(tbl)
+    gid = jnp.asarray([0, 0, 2], jnp.int32)
+    gnp = jnp.asarray([shared, shared, 0], jnp.int32)
+    tl = jnp.asarray([220, 200, 150], jnp.int32)
+    kw = dict(kvp=KVP, rr_block=RR, block_s=pages * PS, block_tables=tbl,
+              interpret=True)
+    if mode == "window":
+        kw["window"] = 100
+    if mode == "append":
+        rng = np.random.default_rng(8)
+        kw["k_new"] = jnp.asarray(rng.standard_normal((B, KH, HSZ),
+                                                      np.float32))
+        kw["v_new"] = jnp.asarray(rng.standard_normal((B, KH, HSZ),
+                                                      np.float32))
+    ru = flash_decode(q, pk, pv, tl, 1, **kw)
+    rg = flash_decode(q, pk, pv, tl, 1, groups=(gid, gnp), **kw)
+    for u, g in zip(ru[:2], rg[:2]):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(u),
+                                   **GROUPED_TOL)
+    for u, g in zip(ru[2:], rg[2:]):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(u))
+    acc = flash_decode_accounting(q, pk, pv, tl, 1, groups=(gid, gnp), **kw)
+    assert acc["prefix_blocks"] == shared // pages

@@ -117,6 +117,47 @@ def test_flash_decode_kvp4_rank_compiles(one_chip):
              ((B, KH, HSZ), jnp.float32), ((B, KH, HSZ), jnp.float32))
 
 
+@pytest.mark.parametrize("case", ["bf16-served", "int8", "kvp4-4row",
+                                  "kvp4-served", "grouped"])
+def test_flash_decode_paged_blocks_compile(one_chip, case):
+    """The paged kernel's S-blocks of whole pages at served shapes: one
+    HOP-B row of bf16 16-row pages against a 1,728-page table, 512-slot
+    blocks (32 pages a step), fused append; an int8 pool with its scales;
+    one KVP=4 rank's 4-row pages, against a 40-page table (the block capped
+    at the table) and against the 1,728-page table (128 pages a block: 256
+    page-slot operands, each double-buffered); and grouped shared-prefix
+    decode, whose prefix pass is its own kernel (``flash_decode_prefix``).
+    """
+    b, rows, pages, dt, kvp, rank = {
+        "bf16-served": (1, PAGE, 1728, jnp.bfloat16, 1, 0),
+        "int8": (B, PAGE, MAX_PAGES, jnp.int8, 1, 0),
+        "kvp4-4row": (B, PAGE // 4, MAX_PAGES, jnp.bfloat16, 4, 2),
+        "kvp4-served": (1, PAGE // 4, 1728, jnp.bfloat16, 4, 2),
+        "grouped": (B, PAGE, MAX_PAGES, jnp.bfloat16, 1, 0),
+    }[case]
+    quant = dt == jnp.int8
+    n_pool = b * pages + 1
+    new_dt = jnp.float32 if quant else dt
+
+    def step(q, k, v, tl, tables, kn, vn, *scales):
+        kw = dict(kscale=scales[0], vscale=scales[1]) if quant else {}
+        if case == "grouped":
+            kw["groups"] = (jnp.zeros_like(tl), tl // rows)
+        return flash_decode(q, k, v, tl, rank, kvp=kvp, rr_block=rows,
+                            block_s=512, k_new=kn, v_new=vn, prune=True,
+                            block_tables=tables, interpret=False, **kw)
+
+    shapes = [((b, QH, HSZ), jnp.bfloat16),
+              ((n_pool, KH, rows, HSZ), dt), ((n_pool, KH, rows, HSZ), dt),
+              ((b,), jnp.int32), ((b, pages), jnp.int32),
+              ((b, KH, HSZ), new_dt), ((b, KH, HSZ), new_dt)]
+    if quant:
+        shapes += [((n_pool, KH, rows), jnp.float32)] * 2
+    hlo = _compile(step, one_chip, *shapes).as_text()
+    assert _kernel_kinds(hlo) == ({"flash_decode", "flash_decode_prefix"}
+                                  if case == "grouped" else {"flash_decode"})
+
+
 def test_flash_prefill_chunked_ragged_compiles(one_chip):
     """A packed prefill chunk: per-row q_offset and valid lengths over the
     carry buffers, causal block skipping."""
@@ -257,8 +298,8 @@ def test_serve_step_scopes_and_kernel_name(topo):
 def test_kernels_are_named():
     """Every Pallas kernel names itself; grouped shared-prefix decode runs
     two, and the prefix pass is ``flash_decode_prefix`` while the suffix
-    pass stays ``flash_decode`` (traced in interpret mode: the grouped
-    prefix pass does not compile for a v5e today)."""
+    pass stays ``flash_decode`` (traced in interpret mode; the compiled
+    pair is ``test_flash_decode_paged_blocks_compile[grouped]``)."""
     q = jnp.zeros((B, QH, HSZ), jnp.float32)
     pool = jnp.zeros((N_POOL, KH, PAGE, HSZ), jnp.float32)
     rows = jnp.zeros((B,), jnp.int32)
