@@ -288,7 +288,8 @@ def init_decode_state(cfg: ArchConfig, batch: int, seq_len: int, kvp: int,
                       kv_bits: int = 16, pool_blocks: int = 0,
                       max_pages: int = 0,
                       grouped: bool = False,
-                      sampling: bool = False) -> dict[str, Any]:
+                      sampling: bool = False,
+                      shardings=None) -> dict[str, Any]:
     """Zero-initialised decode state (concrete arrays, small/test use).
 
     ``kv_bits=8`` allocates int8 K/V payloads plus per-slot f32 scale
@@ -299,12 +300,16 @@ def init_decode_state(cfg: ArchConfig, batch: int, seq_len: int, kvp: int,
     singleton groups under group 0 with no shared prefix, which decodes
     identically to ungrouped).  ``sampling`` adds zeroed per-row sampling
     leaves (all rows greedy — temp 0 — until the engine installs a
-    request's policy at commit/restore time)."""
+    request's policy at commit/restore time).  ``shardings`` (a dict of
+    ``Sharding`` per leaf, e.g. of ``decode_state_specs``) makes each leaf
+    directly split over its devices."""
     shapes = decode_state_shapes(cfg, batch, seq_len, kvp, rr_block, dtype,
                                  kv_bits=kv_bits, pool_blocks=pool_blocks,
                                  max_pages=max_pages, grouped=grouped,
                                  sampling=sampling)
-    state = {k: jnp.zeros(v.shape, v.dtype) for k, v in shapes.items()}
+    state = {k: jnp.zeros(v.shape, v.dtype,
+                          device=(shardings or {}).get(k))
+             for k, v in shapes.items()}
     tl = jnp.asarray(total_len, jnp.int32)
     state["total_len"] = tl
     return state

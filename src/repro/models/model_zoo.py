@@ -13,11 +13,11 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ArchConfig, ShapeCell
 from repro.core.helix import prefill_to_rr_layout
-from repro.core.kvcache import cache_capacity
+from repro.core.kvcache import cache_capacity, cache_to_pages, page_positions
 from repro.core.sharding import HelixConfig, MeshPolicy, train_roles
 from repro.models.decode_model import (  # noqa: F401 re-export
     build_serve_multistep, build_serve_step)
@@ -30,6 +30,7 @@ from repro.utils import round_up
 __all__ = ["make_train_step", "make_prefill_step", "build_serve_step",
            "build_serve_multistep",
            "make_chunk_prefill_step", "init_prefill_buffers",
+           "prefill_buffer_spec", "prefill_to_pool",
            "finalize_chunked_prefill", "chunked_prefill_supported",
            "data_specs", "data_partition_specs", "init_params", "adamw_init"]
 
@@ -156,20 +157,41 @@ def make_prefill_step(cfg: ArchConfig, mesh: Mesh | None, hx: HelixConfig,
 
 
 # ------------------------------------------------------- chunked prefill
+def prefill_buffer_spec(cfg: ArchConfig, mesh: Mesh,
+                        tp_width: int = 1) -> P:
+    """PartitionSpec of the chunked-prefill carry buffers on ``mesh``.
+
+    The padded kv-head axis (``[L, B, T, Kp, hsz]``, dim 3) splits over
+    every mesh axis of size > 1, 'model' first (the forward's own head
+    split), when their product divides Kp; otherwise the buffers are
+    replicated.  The sequence axis stays whole: a prompt's length need not
+    divide the mesh."""
+    from repro.models.attention import head_layout
+    kp = head_layout(cfg.n_heads, cfg.n_kv_heads, tp_width).kv_pad
+    axes = tuple(a for a in sorted(mesh.axis_names, key=lambda a: a != "model")
+                 if mesh.shape[a] > 1)
+    if not axes or kp % math.prod(mesh.shape[a] for a in axes):
+        return P()
+    return P(None, None, None, axes, None)
+
+
 def init_prefill_buffers(cfg: ArchConfig, batch: int, t: int, *,
-                         tp_width: int = 1,
-                         dtype=jnp.float32) -> dict[str, Any]:
+                         tp_width: int = 1, dtype=jnp.float32,
+                         sharding=None) -> dict[str, Any]:
     """Zero K/V carry buffers for a chunked prefill of length ``t``.
 
     Returns {"kcache"/"vcache": [L, batch, t, Kp, hsz]} in ``forward``'s
     prefill cache layout (Kp = the GQA head layout's padded kv head count
     for ``tp_width``, the mesh's 'model' axis size).  ``t`` must equal the
     one-shot prefill length for the chunked run to be bit-exact
-    (docs/serving.md)."""
+    (docs/serving.md).  ``dtype`` is the one the chunk's K/V is computed
+    in; ``sharding`` (a ``Sharding``, e.g. of ``prefill_buffer_spec``)
+    creates them split over its devices, no device holding the whole."""
     from repro.models.attention import head_layout
     kp = head_layout(cfg.n_heads, cfg.n_kv_heads, tp_width).kv_pad
     shape = (cfg.n_layers, batch, t, kp, cfg.hsz)
-    return {"kcache": jnp.zeros(shape, dtype), "vcache": jnp.zeros(shape, dtype)}
+    return {key: jnp.zeros(shape, dtype, device=sharding)
+            for key in ("kcache", "vcache")}
 
 
 def make_chunk_prefill_step(cfg: ArchConfig, mesh: Mesh | None,
@@ -216,6 +238,11 @@ def make_chunk_prefill_step(cfg: ArchConfig, mesh: Mesh | None,
                                      axis=-1).astype(jnp.int32)
         new_buffers = {"kcache": extras["kcache"],
                        "vcache": extras["vcache"]}
+        if mesh is not None:
+            # the carry stays split as init_prefill_buffers placed it
+            new_buffers = jax.lax.with_sharding_constraint(
+                new_buffers, NamedSharding(mesh, prefill_buffer_spec(
+                    cfg, mesh, mesh.shape["model"])))
         if return_last_logits:
             return next_tokens, logits[:, -1], new_buffers
         return next_tokens, new_buffers
@@ -236,6 +263,31 @@ def finalize_chunked_prefill(cfg: ArchConfig, hx: HelixConfig, buffers,
         cfg, hx, buffers["kcache"], buffers["vcache"], t, cap, kvp)
     return {"total_len": jnp.asarray(t, jnp.int32),
             "kcache": kcache, "vcache": vcache}
+
+
+def prefill_to_pool(cfg: ArchConfig, hx: HelixConfig, planes, src, idx, *,
+                    kvp: int, t: int | None = None,
+                    s0: int = 0) -> dict[str, Any]:
+    """A finished prefill's K/V written into the paged pool (jitted by the
+    serving engine as its pool handoff program).
+
+    ``planes`` holds the pool planes ``{"kcache"/"vcache": [L, n_blocks,
+    Kh, block_s, hsz]}``; ``src`` a one-request round-robin prefill state,
+    or, with ``t``, a chunked prefill's carry buffers of ``t`` positions,
+    finalized here (``finalize_chunked_prefill``).  The request's pages
+    ``s0, s0 + 1, ...`` go to the pool planes ``idx``; the updated planes
+    are returned.  Jitted with the planes' shardings as its output's, the
+    buffers' kv-head split becomes the pool's sequence split by
+    all-to-alls, and no device holds a whole copy.  The ops carry the
+    ``kv_pool`` scope (runtime/trace.py)."""
+    block_s = page_positions(kvp, hx.rr_block)
+    with jax.named_scope("kv_pool"):
+        if t is not None:
+            src = finalize_chunked_prefill(cfg, hx, src, t, kvp=kvp)
+        return {key: plane.at[:, idx].set(
+                    cache_to_pages(src[key][:, 0], kvp, block_s)
+                    [:, s0:s0 + idx.shape[0]].astype(plane.dtype))
+                for key, plane in planes.items()}
 
 
 # ------------------------------------------------------------- input data

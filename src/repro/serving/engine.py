@@ -27,6 +27,7 @@ conversion (models/model_zoo.py).  See docs/serving.md for the dataflow.
 """
 from __future__ import annotations
 
+import functools
 import time
 from typing import Any, Callable
 
@@ -206,12 +207,14 @@ class DecodeEngine:
                              "hx.grouped_decode: group_id/group_np are "
                              "host-recomputed every token and would go "
                              "stale mid-window")
+        self._state_shardings = self._decode_state_shardings(mesh)
         self.state = init_decode_state(
             cfg, max_batch, self.cap, kvp, rr_block, dtype=dtype,
             kv_bits=8 if self.kv8 else 16,
             pool_blocks=self.pool_blocks if self.paged else 0,
             max_pages=self.max_pages, grouped=self.grouped,
-            sampling=self.sampling is not None)
+            sampling=self.sampling is not None,
+            shardings=self._state_shardings)
         # per-request lengths: [B]; empty slots keep 0
         self.state["total_len"] = jnp.zeros((max_batch,), jnp.int32)
         self.slots: list[Request | None] = [None] * max_batch
@@ -227,6 +230,17 @@ class DecodeEngine:
         self.chunk_step = (jax.jit(chunk_prefill_step)
                            if chunk_prefill_step is not None else None)
         self.tp_width = tp_width
+        # chunked-prefill carry buffers: in the dtype the chunk computes
+        # K/V in, and split over the mesh (prefill_buffer_spec)
+        self.buf_dtype = self.buf_sharding = None
+        if self.chunk_tokens:
+            self.buf_dtype = jnp.result_type(
+                self.params["embed"], self.params["layers"]["attn"]["wk"])
+            if mesh is not None:
+                from jax.sharding import NamedSharding
+                from repro.models.model_zoo import prefill_buffer_spec
+                self.buf_sharding = NamedSharding(
+                    mesh, prefill_buffer_spec(cfg, mesh, tp_width))
         # host KV tier (serving/tier.py, docs/serving.md): spill live
         # pages on preemption for a zero-re-prefill resume (host_pages
         # sizes it), persist retired requests' pages per session_id for
@@ -292,22 +306,35 @@ class DecodeEngine:
         self._frag_sum, self._frag_n = 0.0, 0
         self._step_num = 0
 
+    def _decode_state_shardings(self, mesh):
+        """The decode state's shardings on ``mesh`` (None without one):
+        the state is made in them, so no device holds a whole pool."""
+        if mesh is None:
+            return None
+        from jax.sharding import PartitionSpec as P
+        from repro.core.kvcache import decode_state_specs
+        from repro.core.sharding import to_shardings
+        hx = self.hx if self.hx is not None else _default_hx(self.rr)
+        specs = decode_state_specs(self.cfg, hx, self.max_batch, mesh,
+                                   sampling=self.sampling is not None)
+        specs["total_len"] = P(None)
+        return to_shardings(mesh, specs)
+
     def _place(self, mesh, serve_step, serve_multistep) -> None:
         """Place params/state on ``mesh`` (see the class doc) and jit the
         decode steps; the windowed step donates the state, so the KV pool
         is not double-buffered across a window dispatch (CPU backends
         don't implement donation and warn, so gate on the platform)."""
-        state_out = None
+        state_out = self._state_shardings
+        self._pool_shardings = None
         if mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec as P
-            from repro.core.kvcache import decode_state_specs
             from repro.core.sharding import helix_param_specs, to_shardings
             hx = self.hx if self.hx is not None else _default_hx(self.rr)
-            specs = decode_state_specs(self.cfg, hx, self.max_batch, mesh,
-                                       sampling=self.sampling is not None)
-            specs["total_len"] = P(None)
-            state_out = to_shardings(mesh, specs)
             self.state = jax.device_put(self.state, state_out)
+            if self.paged:
+                self._pool_shardings = {k: state_out[k]
+                                        for k in ("kcache", "vcache")}
             self.params = jax.device_put(self.params, to_shardings(
                 mesh, helix_param_specs(self.cfg, self.params, hx, mesh)))
             rep = NamedSharding(mesh, P())
@@ -316,6 +343,19 @@ class DecodeEngine:
                                       out_shardings=(rep, state_out))
         else:
             self.serve_step = jax.jit(serve_step)
+        from repro.models.model_zoo import prefill_to_pool
+        self._to_pool = jax.jit(
+            functools.partial(
+                prefill_to_pool, self.cfg,
+                self.hx if self.hx is not None else _default_hx(self.rr),
+                kvp=self.kvp),
+            static_argnames=("t", "s0"),
+            donate_argnums=(0,) if jax.default_backend() != "cpu" else (),
+            out_shardings=self._pool_shardings)
+        self._set_table_row = jax.jit(
+            _set_row, out_shardings=(state_out["block_tables"]
+                                     if self.paged and state_out is not None
+                                     else None))
         self.serve_multistep = None
         if serve_multistep is not None:
             kw = {}
@@ -403,8 +443,8 @@ class DecodeEngine:
                     # pages go back to the free list copy-free
                     # (sched.preempt -> release -> pool.free); park the row
                     # on the sink page
-                    self.state["block_tables"] = \
-                        self.state["block_tables"].at[slot].set(0)
+                    self._write_table_row(
+                        slot, np.zeros((self.max_pages,), np.int32))
                 self.sched.preempt(slot, req)
                 self.metrics.on_preempt(rid, spilled=spilled)
                 return True
@@ -537,7 +577,8 @@ class DecodeEngine:
                 req.prefill_tokens = toks
                 req.prefill_pos = 0
                 req.buffers = init_prefill_buffers(
-                    self.cfg, 1, len(toks), tp_width=self.tp_width)
+                    self.cfg, 1, len(toks), tp_width=self.tp_width,
+                    dtype=self.buf_dtype, sharding=self.buf_sharding)
                 if self.prefix_index is not None:
                     self._prefix_admits += 1
                     if req.shared_len and req.shared_kv is not None:
@@ -812,14 +853,19 @@ class DecodeEngine:
         decode slot and commit the first generated token."""
         from repro.models.model_zoo import finalize_chunked_prefill
         t = len(req.prefill_tokens)
-        hx = self.hx if self.hx is not None else _default_hx(self.rr)
-        pstate = finalize_chunked_prefill(self.cfg, hx, req.buffers, t,
-                                          kvp=self.kvp)
+        # a paged fp pool's handoff program finalizes the buffers itself,
+        # on the devices that hold them (model_zoo.prefill_to_pool)
+        carry = self.paged and not self.kv8
+        pstate = req.buffers
+        if not carry:
+            hx = self.hx if self.hx is not None else _default_hx(self.rr)
+            pstate = finalize_chunked_prefill(self.cfg, hx, pstate, t,
+                                              kvp=self.kvp)
         if self.prefix_index is not None:
             self._register_prefix(req, t)
         req.buffers = None
         req.prefill_tokens = None
-        self._scatter_state(pstate, slot, t, req)
+        self._scatter_state(pstate, slot, t, req, carry=carry)
         return self._commit_first_token(req, slot, first_token)
 
     def _is_resume(self, req: Request) -> bool:
@@ -928,20 +974,29 @@ class DecodeEngine:
         phys = self.pool.pages(self.slots[slot].rid)
         row = np.zeros((self.max_pages,), np.int32)
         row[:len(phys)] = phys
-        self.state["block_tables"] = \
-            self.state["block_tables"].at[slot].set(jnp.asarray(row))
+        self._write_table_row(slot, row)
+
+    def _write_table_row(self, slot: int, row) -> None:
+        """One block-table row written by one dispatch of a program
+        compiled once, its output in the table's sharding: an eager
+        ``.at[slot].set`` of a host row costs three (transfer, reshard,
+        scatter) on the decode step that crosses a page boundary."""
+        self.state["block_tables"] = self._set_table_row(
+            self.state["block_tables"], np.int32(slot), row)
 
     def _scatter_state(self, pstate: dict[str, Any], slot: int,
-                       t: int, req: Request) -> None:
+                       t: int, req: Request, carry: bool = False) -> None:
         """Scatter a single-request prefill state into ``slot`` (copying
         the common round-robin prefix of every rank's local slots; int8
         engines quantize the fp prefill cache per slot row —
         ``quantize_decode_state`` — matching the decode append formula).
         Paged engines instead split the round-robin cache into pages
         (``cache_to_pages``) and write them at the physical pool planes the
-        allocator granted at admission, then install the block-table row."""
+        allocator granted at admission, then install the block-table row.
+        ``carry``: ``pstate`` holds a chunked prefill's carry buffers,
+        which the paged handoff finalizes itself."""
         if self.paged and "kcache" in pstate:
-            self._scatter_paged(pstate, slot, t, req)
+            self._scatter_paged(pstate, slot, t, req, carry)
         elif self.kv8 and "kcache" in pstate:
             fp_slot = {}
             for key in ("kcache", "vcache"):
@@ -971,7 +1026,7 @@ class DecodeEngine:
         self.state["total_len"] = self.state["total_len"].at[slot].set(t)
 
     def _scatter_paged(self, pstate: dict[str, Any], slot: int,
-                       t: int, req: Request) -> None:
+                       t: int, req: Request, carry: bool = False) -> None:
         """Paged half of ``_scatter_state``: prefill cache -> pool pages.
 
         The request's round-robin cache splits into page stacks
@@ -982,10 +1037,9 @@ class DecodeEngine:
         quantize pagewise with the decode-append formula, exactly like the
         fixed path."""
         phys = self.pool.pages(req.rid)
-        pages = {key: cache_to_pages(pstate[key][:, 0], self.kvp,
-                                     self.block_s)
-                 for key in ("kcache", "vcache")}
-        n = min(pages["kcache"].shape[1], len(phys))
+        cap = (cache_capacity(t, self.kvp, self.rr) if carry
+               else pstate["kcache"].shape[3])
+        n = min(-(-cap // self.block_s), len(phys))
         # shared leading pages already hold the registrant's rows —
         # byte-identical to what this request would write for the same
         # token prefix (per-row quantization on kv8), and possibly still
@@ -995,15 +1049,16 @@ class DecodeEngine:
             idx = jnp.asarray(phys[s0:n], jnp.int32)
             if self.kv8:
                 qpages = quantize_decode_state(
-                    {key: pages[key][:, s0:n].astype(jnp.float32)
-                     for key in ("kcache", "vcache")})
+                    {key: cache_to_pages(pstate[key][:, 0], self.kvp,
+                                         self.block_s)[:, s0:n]
+                     .astype(jnp.float32) for key in ("kcache", "vcache")})
                 for key in ("kcache", "vcache", "kscale", "vscale"):
                     self.state[key] = \
                         self.state[key].at[:, idx].set(qpages[key])
             else:
-                for key in ("kcache", "vcache"):
-                    self.state[key] = self.state[key].at[:, idx].set(
-                        pages[key][:, s0:n].astype(self.state[key].dtype))
+                self.state.update(self._to_pool(
+                    {key: self.state[key] for key in ("kcache", "vcache")},
+                    pstate, idx, t=t if carry else None, s0=s0))
         self._mirror_table(slot)
         # (_scatter_state's shared tail installs total_len and ssm leaves)
 
@@ -1100,6 +1155,7 @@ class DecodeEngine:
             if idle:
                 self.state["total_len"] = \
                     self.state["total_len"].at[jnp.asarray(idle)].set(0)
+            self._grow_ahead(active)
         # one batched device->host transfer per step (per-slot int() calls
         # would each block on the device queue — B syncs instead of 1)
         with span("serve.readback"):
@@ -1107,6 +1163,27 @@ class DecodeEngine:
         self.decode_syncs += 1
         with span("serve.replay"):
             return self._replay_step(active, toks_np)
+
+    def _grow_ahead(self, active: list[int]) -> None:
+        """Reserve, while the device runs the step just dispatched, the
+        page each row's *next* token needs, and write its block-table row.
+
+        Growth depends on lengths alone, so it need not wait for the
+        sampled tokens; done here, the table write's dispatch (~1.7 ms of
+        host time on a TPU v5e 2x2 host) overlaps the step instead of
+        delaying the next one.  A row this step retires on max tokens is
+        skipped; one it retires on EOS frees the page with its others in
+        the replay; one the pool cannot serve now grows, or retires on
+        capacity, in the replay's ``_grow_or_retire`` as before."""
+        if not self.paged:
+            return
+        for i in active:
+            req = self.slots[i]
+            if (not req.forced_tokens
+                    and len(req.out_tokens) + 1 >= req.max_new_tokens):
+                continue
+            if self.sched.grow_ahead(i):
+                self._mirror_table(i)
 
     def _replay_step(self, active: list[int], toks_np) -> list[Request]:
         """A decode step's tokens into their requests: forced catch-up,
@@ -1373,8 +1450,8 @@ class DecodeEngine:
         self.state["total_len"] = self.state["total_len"].at[slot].set(0)
         if self.paged:
             # park the freed row on the sink page (all-zero table row)
-            self.state["block_tables"] = \
-                self.state["block_tables"].at[slot].set(0)
+            self._write_table_row(slot,
+                                  np.zeros((self.max_pages,), np.int32))
         self.metrics.on_finish(req.rid, reason)
         return req
 
@@ -1395,6 +1472,10 @@ class DecodeEngine:
                           tokens=req.resume_tokens()[:committed]):
             self.metrics.bump("spills")
         self._sync_store_counters()
+
+
+def _set_row(table, slot, row):
+    return table.at[slot].set(row)
 
 
 def _default_hx(rr_block: int) -> HelixConfig:

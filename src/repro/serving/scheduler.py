@@ -537,6 +537,21 @@ class Scheduler:
             return None
         return self.pool.extend(rid, need - have)
 
+    def grow_ahead(self, slot: int) -> list[int]:
+        """``grow_for_next_token`` one token early: reserve now the page
+        that the token *after* the one in flight needs, if the pool can
+        grant it now.  Returns the new pages, [] otherwise; it never
+        refuses a request — ``grow_for_next_token`` decides that once the
+        token in flight is committed."""
+        if self.pool is None:
+            return []
+        rid = self.slot_rids[slot]
+        need = self.pool.pages_for(self.slot_len[slot] + 2)
+        have = len(self.pool.pages(rid))
+        if need <= have or need > self.max_pages:
+            return []
+        return self.pool.extend(rid, need - have) or []
+
     def grow_for_window(self, slot: int, want: int) -> int:
         """Reserve capacity for up to ``want`` more decode tokens of
         ``slot`` in one shot — the multi-token twin of

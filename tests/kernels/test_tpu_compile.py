@@ -13,6 +13,11 @@ The topology is described inside a module fixture, never at import, so
 every pytest-xdist worker collects the same tests and only the worker that
 runs this file loads the TPU compiler library.
 
+The granite-3.1-8b tests compile the programs of the four-chip KVP=4
+cell at its published widths, its pool and its longest prompt: the
+chunked-prefill step, the pool handoff and the serve step, each within a
+chip's memory, and the serve step's exchange under the ``combine`` scope.
+
 The serve-step test compiles a 2-layer paged decode step and reads its
 HLO: the ``op_name`` metadata carries the step's named scopes
 (runtime/trace.py), which the benchmark's trace reduction attributes
@@ -30,13 +35,17 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
                           SingleDeviceSharding)
 
 from repro.configs import get_config
-from repro.core.kvcache import decode_state_shapes
-from repro.core.sharding import HelixConfig, MeshPolicy, train_roles
+from repro.core.kvcache import (decode_state_shapes, decode_state_specs,
+                                page_positions)
+from repro.core.sharding import (HelixConfig, MeshPolicy, helix_param_specs,
+                                 to_shardings, train_roles)
 from repro.kernels.flash_decode.ops import flash_decode
 from repro.kernels.flash_prefill.ops import flash_prefill
 from repro.kernels.w8a16_matmul.ops import w8a16_matmul
 from repro.models.attention import prefill_attention
 from repro.models.decode_model import build_serve_step
+from repro.models.model_zoo import (make_chunk_prefill_step,
+                                    prefill_buffer_spec, prefill_to_pool)
 from repro.models.transformer import init_params
 from repro.runtime.trace import SCOPES
 
@@ -318,3 +327,110 @@ def test_kernels_are_named():
 
     assert sorted(names(jaxpr.jaxpr)) == ["flash_decode",
                                           "flash_decode_prefix"]
+
+
+# ------------------------------------- granite-3.1-8b, KVP=4 on four chips
+# bench/configs/granite-8b-kvp4.json and bench/traffic/kvp4_long_decode.json
+G8 = dict(n_layers=40, d_model=4096, n_heads=32, n_kv_heads=8, head_dim=128,
+          d_ff=12_800, vocab=49_155, tie_embeddings=True, rope_theta=1e7)
+G8_PROMPT, G8_CHUNK, G8_POOL, G8_NEW = 12_288, 512, 46_080, 1536
+CHIP_BYTES = 16e9
+
+
+@pytest.fixture(scope="module")
+def granite8b_kvp4(four_chips):
+    """(cfg, hx, placed params, placed decode state, buffer shapes) of the
+    four-chip cell, as shapes with the engine's shardings."""
+    mesh = four_chips
+    cfg = dataclasses.replace(get_config("granite-8b"), **G8)
+    hx = HelixConfig(kvp_axes=("data",), paged_kv=True,
+                     attn_backend="pallas", prefill_backend="pallas")
+    params = jax.eval_shape(lambda k: init_params(cfg, k, jnp.bfloat16),
+                            jax.random.PRNGKey(0))
+    psh = to_shardings(mesh, helix_param_specs(cfg, params, hx, mesh))
+    params = jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+        params, psh)
+    n_pool = -(-G8_POOL // page_positions(4, hx.rr_block)) + 1
+    specs = decode_state_specs(cfg, hx, B, mesh)
+    specs["total_len"] = P(None)
+    st_sh = to_shardings(mesh, specs)
+    shapes = decode_state_shapes(cfg, B, G8_PROMPT + G8_NEW + 1, 4,
+                                 hx.rr_block, jnp.bfloat16,
+                                 pool_blocks=n_pool, max_pages=n_pool - 1)
+    shapes["total_len"] = jax.ShapeDtypeStruct((B,), jnp.int32)
+    state = {k: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=st_sh[k])
+             for k, v in shapes.items()}
+    buf = jax.ShapeDtypeStruct(
+        (40, 1, G8_PROMPT, 8, 128), jnp.bfloat16,
+        sharding=NamedSharding(mesh, prefill_buffer_spec(cfg, mesh)))
+    return cfg, hx, params, state, {"kcache": buf, "vcache": buf}
+
+
+def _fits_a_chip(name, compiled):
+    m = compiled.memory_analysis()
+    per_chip = (m.argument_size_in_bytes + m.output_size_in_bytes
+                + m.temp_size_in_bytes)
+    print(f"[granite-8b kvp4] {name}: arguments "
+          f"{m.argument_size_in_bytes:,} B, outputs "
+          f"{m.output_size_in_bytes:,} B, temporaries "
+          f"{m.temp_size_in_bytes:,} B a chip")
+    assert per_chip < CHIP_BYTES, (name, per_chip)
+    return m
+
+
+def test_granite8b_kvp4_chunk_step_compiles(four_chips, granite8b_kvp4):
+    """A 512-token chunk of the 12,288-token prompt: the carry buffers in
+    bf16, kv heads split over the chips, stay so in the output."""
+    cfg, hx, params, _, bufs = granite8b_kvp4
+    rep = NamedSharding(four_chips, P())
+    c = jax.jit(make_chunk_prefill_step(cfg, four_chips, hx)).lower(
+        params, jax.ShapeDtypeStruct((1, G8_CHUNK), jnp.int32, sharding=rep),
+        bufs, jax.ShapeDtypeStruct((1,), jnp.int32, sharding=rep)).compile()
+    m = _fits_a_chip("chunk step", c)
+    quarter = 2 * 40 * G8_PROMPT * 8 * 128 * 2 // 4     # K and V, bf16
+    assert m.output_size_in_bytes < 1.01 * quarter
+    assert c.output_shardings[1]["kcache"].is_equivalent_to(
+        bufs["kcache"].sharding, 5)
+
+
+def test_granite8b_kvp4_pool_handoff_compiles(four_chips, granite8b_kvp4):
+    """The handoff of the finished 12,288-token prefill into the pool:
+    the kv-head split becomes the pool's sequence split by all-to-alls,
+    with no all-gather of the buffers."""
+    import functools
+    cfg, hx, _, state, bufs = granite8b_kvp4
+    planes = {k: state[k] for k in ("kcache", "vcache")}
+    sh = {k: v.sharding for k, v in planes.items()}
+    fn = jax.jit(functools.partial(prefill_to_pool, cfg, hx, kvp=4),
+                 static_argnames=("t", "s0"), donate_argnums=(0,),
+                 out_shardings=sh)
+    n = -(-G8_PROMPT // page_positions(4, hx.rr_block))
+    c = fn.lower(planes, bufs, jax.ShapeDtypeStruct(
+        (n,), jnp.int32, sharding=NamedSharding(four_chips, P())),
+        t=G8_PROMPT, s0=0).compile()
+    m = _fits_a_chip("pool handoff", c)
+    pool = sum(v.size * v.dtype.itemsize for v in planes.values()) // 4
+    assert m.alias_size_in_bytes == pool                   # donated
+    ops = {op for _, _, op, _, _ in _instructions(c.as_text())}
+    assert "all-to-all" in ops and not {"all-gather", "all-gather-start"} & ops
+
+
+def test_granite8b_kvp4_serve_step_compiles(four_chips, granite8b_kvp4):
+    """The decode step of 4 rows over the 46,080-token pool, and every
+    op of the exchange (all-to-all, all-gather, async or not) under the
+    ``combine`` scope."""
+    cfg, hx, params, state, _ = granite8b_kvp4
+    rep = NamedSharding(four_chips, P())
+    st_sh = {k: v.sharding for k, v in state.items()}
+    c = jax.jit(build_serve_step(cfg, four_chips, hx),
+                out_shardings=(rep, st_sh)).lower(
+        params, state, jax.ShapeDtypeStruct((B,), jnp.int32,
+                                            sharding=rep)).compile()
+    _fits_a_chip("serve step", c)
+    exchange = [(name, op, on) for name, _, op, on, _ in
+                _instructions(c.as_text())
+                if re.match(r"all-(to-all|gather)", op) and "/attn/" in on]
+    assert "all-to-all" in {op for _, op, _ in exchange}
+    for name, op, on in exchange:
+        assert _scope(on) == "combine", (name, op, on)

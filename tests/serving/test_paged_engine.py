@@ -249,3 +249,81 @@ def test_paged_preempt_resume_identical_tokens():
     plain = run(False)
     resumed = run(True)
     assert plain == resumed
+
+
+# ---------------------------------------------- growth ahead of the readback
+def _phases_of_table_writes(eng):
+    """Record, for each block-table write, the decode phase it ran in."""
+    seen, phase = [], ["other"]
+
+    def wrap(name):
+        fn = getattr(eng, name)
+
+        def run(*a, **kw):
+            phase[0] = name
+            try:
+                return fn(*a, **kw)
+            finally:
+                phase[0] = "other"
+        setattr(eng, name, run)
+
+    wrap("_grow_ahead")
+    wrap("_replay_step")
+    mirror = eng._mirror_table
+
+    def record(slot):
+        seen.append(phase[0])
+        return mirror(slot)
+    eng._mirror_table = record
+    return seen
+
+
+@pytest.mark.parametrize("chunk", [0, 5])
+def test_decode_growth_is_written_while_the_step_runs(chunk):
+    """A paged decode step reserves the page of each row's next token and
+    writes its table row right after dispatch, before the readback, so no
+    table write lies between two steps; the streams equal the fixed
+    layout's and the pool drains."""
+    # 16-position pages: positions up to L + 38 need 3, 2 and 3 pages more
+    # than the L + 1 that admission grants
+    lengths, max_new = (14, 9, 30), 40
+    fixed, _ = _run(_hx("ref"), chunk=chunk, lengths=lengths,
+                    max_new=max_new, max_seq=80)
+    eng = _engine(_hx("ref", paged=True), chunk=chunk, max_seq=80)
+    writes = _phases_of_table_writes(eng)
+    reqs = [Request(rid=i, prompt=p, max_new_tokens=max_new)
+            for i, p in enumerate(_prompts(lengths))]
+    with set_mesh(MESH):
+        for r in reqs:
+            eng.submit(r)
+        eng.run_to_completion()
+    assert [tuple(r.out_tokens) for r in reqs] == fixed
+    assert writes.count("_grow_ahead") == 8
+    assert "_replay_step" not in writes
+    assert eng.pool.free_count == eng.pool.capacity
+
+
+def test_eos_row_frees_the_page_grown_ahead():
+    """A row that stops on EOS at the step whose next token would open a
+    page reserved that page while the step ran, and gives it back with
+    its others."""
+    hx = _hx("ref", paged=True)
+    prompt = _prompts((13,))[0]          # the prompt _run serves
+    stream, eng = _run(hx, lengths=(13,), max_new=12, max_seq=48)
+    stream = list(stream[0])
+    # decode step j writes position 13 + j - 1 and emits out token j; the
+    # step emitting token k = 16 - 13 writes position 15, the last of the
+    # first page, so it grows the second page ahead
+    k = eng.block_s - len(prompt)
+    assert k == 3 and stream[k] not in stream[:k]
+    eng = _engine(hx, max_seq=48)
+    writes = _phases_of_table_writes(eng)
+    r = Request(rid=0, prompt=list(prompt), max_new_tokens=12,
+                eos_id=stream[k])
+    with set_mesh(MESH):
+        eng.submit(r)
+        eng.run_to_completion()
+    assert r.finish_reason == "eos" and r.out_tokens == stream[:k + 1]
+    assert writes.count("_grow_ahead") == 1
+    assert eng.pool.peak_in_use == 2
+    assert eng.pool.free_count == eng.pool.capacity

@@ -216,3 +216,28 @@ def test_preempt_requeues_at_front():
     assert [r.rid for r in sched.queue] == [0, 1]
     [(resumed, _)] = sched.admit()
     assert resumed is a
+
+
+def test_grow_ahead_reserves_the_page_after_next_and_never_refuses():
+    """``grow_ahead`` grants the page that the token after the one in
+    flight needs, only at a page boundary and only from free pages; it
+    returns [] where ``grow_for_next_token`` would refuse."""
+    from repro.serving.pool import BlockAllocator
+    pool = BlockAllocator(n_blocks=3, block_s=4)          # 2 allocatable
+    s = Scheduler(max_batch=1, cap=64, pool=pool, max_pages=8)
+    s.submit(Request(rid=0, prompt=[1, 2], max_new_tokens=20))
+    [(req, slot)] = s.admit()
+    assert len(pool.pages(0)) == 1 and s.slot_len[slot] == 2
+    assert s.grow_ahead(slot) == []                       # pos 3: page 1
+    s.on_token(slot)
+    got = s.grow_ahead(slot)                              # pos 4: page 2
+    assert len(got) == 1 and pool.free_count == 0
+    s.on_token(slot)
+    assert s.grow_for_next_token(slot) == []              # already there
+    s.on_token(slot)
+    s.on_token(slot)
+    s.on_token(slot)                                      # slot_len 7
+    assert s.grow_ahead(slot) == []                       # pos 8: no page
+    s.on_token(slot)
+    assert s.grow_for_next_token(slot) is None            # refusal is here
+    assert Scheduler(max_batch=1, cap=8).grow_ahead(0) == []
